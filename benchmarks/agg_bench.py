@@ -159,13 +159,13 @@ def main(smoke: bool = False) -> tuple[list[tuple], list[dict], list[dict]]:
             us = _time(f, x, reps=reps)
             # derived: throughput in M coords / s
             rows.append((f"agg/{name}/K{k}_M{m}", us, m / us, None, 0))
-        f = jax.jit(lambda v: ops.mm_aggregate(v, interpret=True))
+        f = jax.jit(lambda v: ops.mm_aggregate(v))
         us = _time(f, x, reps=reps)
         rows.append((f"agg/mm_pallas_interp/K{k}_M{m}", us, m / us,
                      fused_bytes, 1))
         # weighted single-array kernel path (Eq. 13's a_k inside the kernel)
         a = jnp.linspace(0.5, 1.5, k)
-        fw = jax.jit(lambda v, w: ops.mm_aggregate(v, w, interpret=True))
+        fw = jax.jit(lambda v, w: ops.mm_aggregate(v, w))
         us = _time(fw, x, a, reps=reps)
         rows.append((f"agg/mm_pallas_weighted/K{k}_M{m}", us, m / us,
                      fused_bytes, 1))
@@ -175,9 +175,8 @@ def main(smoke: bool = False) -> tuple[list[tuple], list[dict], list[dict]]:
                                     minval=0.1, maxval=1.0)
             pn = mk.launch_plan(k, m, n)
             fb = jax.jit(
-                lambda v, w: ops.mm_aggregate_batched(v, w, interpret=True))
-            launches = count_pallas_calls(lambda v, w: ops.mm_aggregate_batched(
-                v, w, interpret=True), x, an)
+                lambda v, w: ops.mm_aggregate_batched(v, w))
+            launches = count_pallas_calls(lambda v, w: ops.mm_aggregate_batched(v, w), x, an)
             assert launches == 1, launches
             us = _time(fb, x, an, reps=reps)
             rows.append((f"agg/mm_pallas_batched/K{k}_M{m}_N{n}", us,
@@ -195,8 +194,7 @@ def main(smoke: bool = False) -> tuple[list[tuple], list[dict], list[dict]]:
         x = jax.random.normal(jax.random.key(2), (k, m))
         x = x.at[-k // 4:].add(100.0)
         plan = mk.launch_plan(k, m, 1, path="two_pass")
-        f2 = jax.jit(lambda v: ops.mm_aggregate(v, interpret=True,
-                                                path="two_pass"))
+        f2 = jax.jit(lambda v: ops.mm_aggregate(v, path="two_pass"))
         _assert_finite(f"mm_pallas_two_pass/K{k}_M{m}", f2(x))
         us = _time(f2, x, reps=reps)
         rows.append((f"agg/mm_pallas_two_pass/K{k}_M{m}", us, m / us,
@@ -211,8 +209,7 @@ def main(smoke: bool = False) -> tuple[list[tuple], list[dict], list[dict]]:
     converged = ref.mm_aggregate_ref(x_i, num_iters=50)
     irls_rows = []
     for t in IRLS_DEPTHS:
-        ft = jax.jit(lambda v, _t=t: ops.mm_aggregate(
-            v, interpret=True, num_iters=_t))
+        ft = jax.jit(lambda v, _t=t: ops.mm_aggregate(v, num_iters=_t))
         out = ft(x_i)
         _assert_finite(f"irls_depth/T{t}", out)
         us = _time(ft, x_i, reps=reps)
@@ -279,7 +276,7 @@ def main(smoke: bool = False) -> tuple[list[tuple], list[dict], list[dict]]:
         a = jnp.linspace(0.5, 1.5, k)
         n_leaves = len(jax.tree.leaves(tree))
         m_total = sum(int(l.size) // k for l in jax.tree.leaves(tree))
-        eng = ops.AggregationEngine(interpret=True)
+        eng = ops.AggregationEngine()
         launches = count_pallas_calls(
             lambda t, w: eng.aggregate_tree(t, w), tree, a)
         assert launches == 1, f"expected ONE kernel launch, got {launches}"

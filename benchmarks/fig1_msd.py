@@ -15,6 +15,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compat
 from repro.configs import paper_lsq
 from repro.core import attacks, diffusion, graph
 from repro.data import synthetic
@@ -113,5 +114,6 @@ def main(iters: int = None, out_dir: str = "experiments") -> list[tuple]:
 
 
 if __name__ == "__main__":
+    compat.enable_persistent_compilation_cache()
     for name, us, derived in main():
         print(f"{name},{us:.2f},{derived:.6g}")

@@ -22,6 +22,8 @@ def main() -> None:
     ap.add_argument("--fig1-iters", type=int, default=None)
     args = ap.parse_args()
     wanted = set(args.only.split(","))
+    from repro import compat
+    compat.enable_persistent_compilation_cache()
 
     suites = []
     if "fig1" in wanted:

@@ -189,12 +189,11 @@ def main(argv=None) -> int:
                     help="write BENCH_scenarios.json-style output")
     ns = ap.parse_args(argv)
 
-    # env-guarded persistent XLA compile cache: sweep re-runs (and the
-    # other ci.sh benchmark processes) amortize compiles across
-    # processes the way REPRO_TUNING_CACHE amortizes block sweeps
-    cache_dir = compat.enable_persistent_compilation_cache()
-    if cache_dir:
-        print(f"persistent compilation cache: {cache_dir}")
+    # persistent XLA compile cache: sweep re-runs (and the other ci.sh
+    # benchmark processes) amortize compiles across processes the way
+    # REPRO_TUNING_CACHE amortizes block sweeps
+    print("persistent compilation cache: "
+          f"{compat.enable_persistent_compilation_cache()}")
 
     specs = build_specs(ns)
     rows = []

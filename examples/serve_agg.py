@@ -15,6 +15,7 @@ import dataclasses
 import json
 import sys
 
+from repro import compat
 from repro.scenarios.spec import ScenarioSpec
 from repro.serve import CHAOS_PROFILES, ServeConfig, replay
 
@@ -38,6 +39,7 @@ def main():
                          "it from its journal (repeatable, in (0, 1))")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compat.enable_persistent_compilation_cache()
 
     spec = ScenarioSpec(
         name=f"serve-demo-{args.profile}", paradigm="federated",

@@ -3,8 +3,9 @@ data-parallel gradient aggregation (the paper's technique lifted to the
 training framework).
 
 Default: a ~20M-param qwen3-family model, 300 steps, 8 simulated agents
-(forced host devices), one of which sends additively-corrupted
-gradients.  Compares mean vs REF (rs_mm) aggregation.
+(``--agents 8``, whatever the device count), one of which sends
+additively-corrupted gradients.  Compares mean vs REF (rs_mm)
+aggregation.
 
   PYTHONPATH=src python examples/train_robust_lm.py            # ~20M
   PYTHONPATH=src python examples/train_robust_lm.py --big      # ~100M
@@ -31,13 +32,13 @@ def run(agg, malicious, args):
         "--layers", str(args.layers),
         "--d-model", str(args.d_model),
         "--aggregation", agg,
+        "--agents", "8",
         "--malicious", str(malicious),
         "--delta", "100.0",
         "--lr", "3e-3",
     ]
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     print(f"\n=== aggregation={agg} malicious={malicious} ===")
     proc = subprocess.run(cmd, env=env, text=True, capture_output=True)
     print(proc.stdout)

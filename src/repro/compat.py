@@ -1,113 +1,61 @@
-"""Version-adaptive shims over the jax sharding API.
+"""Thin helpers over the jax sharding API, plus the process-level
+compile-cache switch the entry points share.
 
-The launch/test code is written against the modern surface
-(``jax.shard_map``, ``jax.sharding.AxisType``,
-``jax.sharding.get_abstract_mesh``); older installs (<= 0.4.x) expose
-the same machinery under ``jax.experimental.shard_map`` with
-``check_rep``/``auto`` instead of ``check_vma``/``axis_names`` and have
-no abstract-mesh context.  Everything in the repo goes through this
-module so a single interpreter can run either line.
+The repo is written against one jax line (``pyproject.toml``): these
+helpers only fix the repo's conventions -- every mesh axis is Auto,
+``shard_map`` takes the manual axes as a set -- so call sites stay short.
 """
 
 from __future__ import annotations
 
 import os
+import pathlib
 
 import jax
 
-HAS_MODERN_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-
-# Nested manualization (an inner shard_map that manualizes the 'model'
-# axis from inside an agent-manual region) and partial-auto sharding
-# constraints are memory optimizations that need the modern stack; on
-# the legacy API the callers fall back to identity wrappers.
-SUPPORTS_NESTED_MANUAL = HAS_MODERN_SHARD_MAP and HAS_ABSTRACT_MESH
-
-
 COMPILATION_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed in-checkout default (listed in .gitignore, and what ci.sh uses):
+# the cache key includes the path, so a moving directory never hits
+DEFAULT_COMPILATION_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[2] / ".jax_compile_cache")
 
 
-def enable_persistent_compilation_cache():
-    """Env-guarded switch for jax's persistent (on-disk) compilation
-    cache, mirroring the ``REPRO_TUNING_CACHE`` pattern: when
-    ``$JAX_COMPILATION_CACHE_DIR`` names a directory, point jax's cache
-    there and drop the min-compile-time/min-entry-size thresholds so
-    even the small smoke-sweep programs persist -- repeated sweep /
-    benchmark processes then amortize XLA compiles across runs instead
-    of re-paying them per process.  Returns the cache dir, or None when
-    the env var is unset (no config is touched -- in-process behavior
-    is exactly as before).  Version-tolerant: unknown config names on
-    older jax lines are ignored.
-    """
-    path = os.environ.get(COMPILATION_CACHE_ENV)
-    if not path:
-        return None
-    for name, value in (
-            ("jax_compilation_cache_dir", path),
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(name, value)
-        except (AttributeError, KeyError, ValueError):  # pragma: no cover
-            pass    # older jax: best effort, never fatal
+def enable_persistent_compilation_cache() -> str:
+    """Point jax's persistent (on-disk) compilation cache at
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set, else at the fixed
+    ``<repo>/.jax_compile_cache``, and drop the min-compile-time /
+    min-entry-size thresholds so even small programs persist.  Called
+    once by each entry point (scripts, benchmarks, the trainer), never
+    at import.  Returns the directory used."""
+    path = os.environ.get(COMPILATION_CACHE_ENV) or DEFAULT_COMPILATION_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
 
 
 def make_mesh(axis_shapes, axis_names):
-    """An all-Auto mesh on either API line."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """A mesh whose axes are all Auto (GSPMD decides the layouts)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def get_abstract_mesh():
-    """The mesh of the current trace context, or None.
-
-    Modern jax tracks an abstract mesh (with Manual/Auto axis types
-    reflecting shard_map regions); legacy jax only has the thread-local
-    physical mesh activated by ``with mesh:``.
-    """
-    if HAS_ABSTRACT_MESH:
-        am = jax.sharding.get_abstract_mesh()
-        return am if am is not None and am.shape else None
-    from jax._src import mesh as _mesh_lib  # legacy thread-local
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The mesh of the current trace context, or None when no mesh (or
+    an empty one) is active."""
+    am = jax.sharding.get_abstract_mesh()
+    return am if am is not None and am.shape else None
 
 
 def shard_map(f, mesh=None, *, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """``jax.shard_map`` with the modern signature on either API line.
-
-    ``axis_names`` is the set of *manual* axes; on the legacy API it is
-    translated to ``auto`` (its complement) and ``check_vma`` to
-    ``check_rep``.  ``mesh=None`` resolves the context mesh (legacy
-    needs a concrete mesh and takes the active physical one).
-    """
-    if HAS_MODERN_SHARD_MAP:
-        kwargs = {}
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check_vma, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _legacy_sm
-    if mesh is None:
-        mesh = get_abstract_mesh()
-        if mesh is None:
-            raise ValueError(
-                "legacy shard_map needs a concrete mesh: pass mesh= or "
-                "activate one with `with mesh:` / sharding.use_mesh")
-    # Partial-auto (auto=...) hard-crashes the legacy XLA partitioner
-    # (IsManualSubgroup check), so the region runs FULLY manual: axes
-    # not named by the specs replicate their operands, i.e. model-axis
-    # tensor parallelism degrades to replicated compute inside manual
-    # regions.  Numerically identical; only the memory win is lost.
-    return _legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` with ``axis_names`` (the manual axes) taken as
+    any iterable; ``mesh=None`` resolves the context mesh."""
+    kwargs = {}
+    if mesh is not None:
+        kwargs["mesh"] = mesh
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma, **kwargs)

@@ -121,6 +121,8 @@ _MAX_BLOCK_K2 = 512
 _CHUNK_BUDGET_BYTES = 2 * 2 ** 20
 # trace-size guard: never split N into more than this many chunks
 _MAX_N_CHUNKS = 16
+# rows per block of the weighted-median prefix scan (see _wquantile_planes)
+_SCAN_BLOCK = 16
 
 
 def next_pow2(n: int) -> int:
@@ -197,6 +199,18 @@ def _bitonic_sort_rows(x: jnp.ndarray, carries: Tuple[jnp.ndarray, ...] = ()
     return x, carries
 
 
+def _weight_planes(a: jnp.ndarray, bm: int) -> jnp.ndarray:
+    """(P, N) weight columns -> (P, N, bm) planes for the paired sort.
+
+    Mosaic cannot reshape a bare broadcast (its lane-replicated layout
+    aborts the compiler inside the sort network's row reshapes), so the
+    planes are materialized through a select against a lane iota, which
+    is always true.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, bm), 2)
+    return jnp.where(lane >= 0, a[:, :, None], 0.0)
+
+
 def _median_rows(x_sorted: jnp.ndarray, k: int) -> jnp.ndarray:
     """Median of the first k (valid) rows of an ascending-sorted tile whose
     pad rows are +inf (and therefore sorted to the end)."""
@@ -215,12 +229,33 @@ def _wquantile_planes(xs: jnp.ndarray, ws: jnp.ndarray, half) -> jnp.ndarray:
     mass).  Sentinel rows carry weight 0 and sort to the end, so they
     are never selected; an all-zero plane selects nothing and returns
     0.  Returns (N, bm).
+
+    Mosaic has no cumsum, so the cumulative weight is a blocked
+    sequential scan: rows accumulate left to right inside blocks of
+    ``_SCAN_BLOCK`` (all blocks at once), and block b starts exactly
+    where block b-1 ended.  Up to ``_SCAN_BLOCK`` rows this is the
+    oracle's ``jnp.cumsum`` order; at any size it is monotone in
+    floating point, so at most one row crosses.
     """
-    cw = jnp.cumsum(ws, axis=0)
-    prev = jnp.concatenate([jnp.zeros_like(cw[:1]), cw[:-1]], axis=0)
-    sel = (cw >= half) & (prev < half)
     vals = xs if xs.ndim == ws.ndim else xs[:, None, :]
-    return jnp.sum(jnp.where(sel, vals, 0.0), axis=0)
+    p = ws.shape[0]
+    g = min(p, _SCAN_BLOCK)            # p is a power of two, so g | p
+    wb = ws.reshape((p // g, g) + ws.shape[1:])
+    vb = vals.reshape((p // g, g) + vals.shape[1:])
+    local = [wb[:, 0]]                 # in-block running sums
+    for i in range(1, g):
+        local.append(local[-1] + wb[:, i])
+    start = [jnp.zeros(ws.shape[1:], jnp.float32)]
+    for b in range(1, p // g):
+        start.append(start[-1] + local[-1][b - 1])
+    prev = jnp.stack(start)            # (blocks, N, bm)
+    start, out = prev, jnp.zeros_like(prev)
+    for i in range(g):
+        cw = start + local[i]
+        out = jnp.where((cw >= half) & (prev < half), vb[:, i], out)
+        prev = cw
+    # the crossing row lies in one block; the others hold zeros
+    return jnp.sum(out, axis=0)
 
 
 def _weighted_median_planes(xs: jnp.ndarray, ws: jnp.ndarray) -> jnp.ndarray:
@@ -270,8 +305,7 @@ def _mm_kernel(x_ref, a_ref, o_ref, xs_ref, *, k: int, block_k: int,
         # --- robust init: (weighted) median + MAD, one shared sort ---
         if weighted:
             # carry every weight plane through the single value sort
-            planes = jnp.broadcast_to(a[:, :, None], (p, n_out, bm))
-            xs, (ws,) = _bitonic_sort_rows(xp, (planes,))
+            xs, (ws,) = _bitonic_sort_rows(xp, (_weight_planes(a, bm),))
             med = _weighted_median_planes(xs, ws)    # (N, bm)
         else:
             xs, _ = _bitonic_sort_rows(xp)
@@ -333,11 +367,12 @@ def _mm_two_pass_kernel(x_ref, a_ref, o_ref, xs_ref, med_ref, mad_ref, *,
     for c0 in range(0, n_out, n_chunk):
         nc = min(n_chunk, n_out - c0)
         if weighted:
-            ac = a_blk[:, c0:c0 + nc]                          # (bk, nc)
-            planes = jnp.broadcast_to(ac[:, :, None], (bk, nc, bm))
-            xs, (ws,) = _bitonic_sort_rows(xinf, (planes,))
-            # block weighted median: crossing at half the BLOCK mass
-            half = 0.5 * jnp.sum(ws, axis=0)                   # (nc, bm)
+            xs, (ws,) = _bitonic_sort_rows(
+                xinf, (_weight_planes(a_blk[:, c0:c0 + nc], bm),))
+            # block weighted median: crossing at half the BLOCK mass.
+            # One block holds all of the (normalized) mass, so the
+            # threshold is the oracle's exact 0.5, not a rounded sum.
+            half = 0.5 if kb == 1 else 0.5 * jnp.sum(ws, axis=0)
             med_c = _wquantile_planes(xs, ws, half)            # (nc, bm)
         else:
             xs, _ = _bitonic_sort_rows(xinf)
@@ -366,13 +401,11 @@ def _mm_two_pass_kernel(x_ref, a_ref, o_ref, xs_ref, med_ref, mad_ref, *,
             mads = jnp.concatenate([mads, pad], axis=0)
             mass = jnp.concatenate(
                 [mass, jnp.zeros((kbp - kb, n_out), jnp.float32)], axis=0)
-        xsv = xs_ref[...]                    # (K_pad, bm), zeros on pads
         c2 = jnp.float32(c * c)
 
         for c0 in range(0, n_out, n_chunk):
             nc = min(n_chunk, n_out - c0)
-            mass_c = jnp.broadcast_to(
-                mass[:, c0:c0 + nc, None], (kbp, nc, bm))
+            mass_c = _weight_planes(mass[:, c0:c0 + nc], bm)
             half = 0.5 * jnp.sum(mass_c, axis=0)               # (nc, bm)
             # init: mass-weighted median of block medians; scale: pooled
             # mass-weighted median of block MADs.  Exact when KB == 1.
@@ -382,15 +415,15 @@ def _mm_two_pass_kernel(x_ref, a_ref, o_ref, xs_ref, med_ref, mad_ref, *,
             scale = jnp.maximum(
                 _MAD_CONSISTENCY * _wquantile_planes(ss, sw, half),
                 _SCALE_FLOOR)
-            ac = a[:, c0:c0 + nc]                              # (K_pad, nc)
 
-            def body(t, mu, _ac=ac, _scale=scale, _nc=nc):
+            def body(t, mu, _c0=c0, _scale=scale, _nc=nc):
                 # the IRLS num/den sums decompose exactly over K blocks:
                 # walk the residency block by block, (bk, nc, bm) live
                 def blk(b, acc):
                     num, den = acc
-                    xb_b = jax.lax.dynamic_slice(xsv, (b * bk, 0), (bk, bm))
-                    a_b = jax.lax.dynamic_slice(_ac, (b * bk, 0), (bk, _nc))
+                    rows = pl.ds(pl.multiple_of(b * bk, bk), bk)
+                    xb_b = xs_ref[rows, :]             # zeros on pads
+                    a_b = a_ref[rows, _c0:_c0 + _nc].astype(jnp.float32)
                     y = (xb_b[:, None, :] - mu[None]) / _scale[None]
                     u = jnp.clip(1.0 - (y * y) / c2, 0.0, 1.0)
                     w = a_b[:, :, None] * (u * u)              # a_k * b_k

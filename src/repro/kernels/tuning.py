@@ -286,18 +286,25 @@ def _time_call_us(fn, *args, reps: int = 3) -> float:
     return (time.perf_counter() - t0) / reps * 1e6
 
 
+def sublane_rows(dtype) -> int:
+    """Row tile of one vreg for ``dtype``: a K block shorter than the
+    padded K axis must be a multiple of it (8 rows for 32-bit, 16 for
+    16-bit) or Mosaic refuses the BlockSpec."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 def candidate_blocks(k: int, m: int, n: int = 1,
                      dtype=jnp.float32) -> Sequence[BlockChoice]:
     """Default single-pass sweep: lane tiles around the heuristic,
-    full-K streaming plus one K-split when the padded K axis is large
-    enough to split."""
+    full-K streaming plus one K-split when half the padded K axis is a
+    whole number of sublane tiles."""
     bms = sorted({LANE, 256, 512, heuristic_blocks(k, m, n, dtype)[0]})
     m_lanes = max(LANE, ((int(m) + LANE - 1) // LANE) * LANE)
     bms = [bm for bm in bms if bm <= m_lanes] or [LANE]
     bks: list = [None]
-    k_even = int(k) + (int(k) % 2)
-    if k_even >= 16:
-        bks.append(k_even // 2 if k_even % 4 == 0 else None)
+    half = (int(k) + (int(k) % 2)) // 2
+    if half % sublane_rows(dtype) == 0:
+        bks.append(half)
     out = []
     for bm in bms:
         for bk in bks:
@@ -341,8 +348,9 @@ def autotune(k: int, m: int, n: int = 1, dtype=jnp.float32, *,
     cache and return the fastest (the cached ``TuneChoice`` keeps the
     measured path; the returned pair stays (block_m, block_k) for
     callers that only size tiles).  Idempotent per (K, M, N, dtype)
-    unless ``force``; failures of individual candidates are skipped
-    (e.g. a tile too large for the backend)."""
+    unless ``force``.  A candidate the backend refuses raises: every
+    candidate offered is meant to run, so a refusal is a bug in the
+    candidate list, not a slow geometry."""
     from repro.kernels import mm_aggregate as _mk  # full module, lazily
 
     key = _key(k, m, n, dtype)
@@ -361,14 +369,9 @@ def autotune(k: int, m: int, n: int = 1, dtype=jnp.float32, *,
             return _mk.mm_aggregate_batched_2d(
                 xv, av, num_iters=num_iters, block_m=_c.block_m,
                 block_k=_c.block_k, path=_c.path, interpret=interpret)
-        try:
-            us = _time_call_us(jax.jit(run), x, a, reps=reps)
-        except Exception:
-            continue
+        us = _time_call_us(jax.jit(run), x, a, reps=reps)
         if us < best_us:
             best, best_us = cand, us
-    if best is None:    # every candidate failed: fall back, don't cache
-        return heuristic_blocks(k, m, n, dtype)
     _CACHE[key] = best
     save_cache()        # best-effort persist of the measured winner
     return (best.block_m, best.block_k)

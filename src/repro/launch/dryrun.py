@@ -8,8 +8,10 @@ Usage:
 """
 
 # The VERY FIRST lines, before any other import: jax locks the device
-# count on first init.  Dry-run only -- tests/benches must see 1 device.
+# count (and platform) on first init.  Dry-run only, on host devices --
+# tests/benches must see 1 device, and a chip is never touched.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", ""))

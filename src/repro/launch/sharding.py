@@ -39,32 +39,22 @@ DEFAULT_RULES: dict[str, Tuple[str, ...]] = {
 class _Ctx(threading.local):
     mesh: Optional[Mesh] = None
     rules: Optional[dict] = None
-    skip_constraints: bool = False
 
 
 _CTX = _Ctx()
 
 
 @contextlib.contextmanager
-def use_mesh(mesh: Mesh, rules: Optional[dict] = None,
-             manual_region: bool = False):
-    """Activate logical sharding (and the jax mesh context) for a region.
-
-    ``manual_region=True`` marks a shard_map body: on legacy jax a
-    partial-auto sharding constraint inside a manual region hard-crashes
-    GSPMD (IsManualSubgroup check), so ``shard`` degrades to identity
-    there -- the constraints are memory-layout hints, not semantics.
-    """
-    prev = (_CTX.mesh, _CTX.rules, _CTX.skip_constraints)
+def use_mesh(mesh: Mesh, rules: Optional[dict] = None):
+    """Activate logical sharding (and the jax mesh context) for a region."""
+    prev = (_CTX.mesh, _CTX.rules)
     _CTX.mesh = mesh
     _CTX.rules = dict(DEFAULT_RULES, **(rules or {}))
-    _CTX.skip_constraints = (manual_region
-                             and not compat.SUPPORTS_NESTED_MANUAL)
     try:
         with mesh:
             yield
     finally:
-        _CTX.mesh, _CTX.rules, _CTX.skip_constraints = prev
+        _CTX.mesh, _CTX.rules = prev
 
 
 def active_mesh() -> Optional[Mesh]:
@@ -122,17 +112,12 @@ def shard(x, *names: Optional[str]):
         return x
     if len(names) != x.ndim:
         raise ValueError(f"{len(names)} names for rank-{x.ndim} array")
-    if _CTX.skip_constraints:
-        return x
     spec = logical_spec(names, x.shape, mesh, rules)
     # Inside jit/shard_map the constraint must be built against the
     # *abstract* context mesh (whose axis_types reflect Manual regions);
     # the concrete mesh is only used for shape/divisibility decisions.
-    try:
-        am = compat.get_abstract_mesh()
-        target = am if am is not None else mesh
-    except Exception:  # noqa: BLE001 -- API drift safety
-        target = mesh
+    am = compat.get_abstract_mesh()
+    target = am if am is not None else mesh
     return jax.lax.with_sharding_constraint(x, NamedSharding(target, spec))
 
 

@@ -235,6 +235,16 @@ def _mm_axis0(flat, num_iters: int, use_kernel: bool = False):
     return agg(flat, None)
 
 
+def _mm_axis0_laid_out(g, spec: P, mesh, par: ParallelConfig):
+    """MM over axis 0 of ``g``, which is laid out as ``spec`` (axis 0
+    unsharded).  GSPMD cannot partition a Pallas kernel, so every device
+    aggregates its own coordinate shard inside shard_map -- exact for
+    both backends, since the estimate is elementwise."""
+    return compat.shard_map(
+        lambda t: _mm_axis0(t, par.agg_num_iters, par.use_kernel),
+        mesh=mesh, in_specs=spec, out_specs=P(*spec[1:]))(g)
+
+
 def aggregate_stack(grads, mesh, par: ParallelConfig,
                     out_specs, agg_axes) -> dict:
     """Aggregate per-agent gradient pytrees (leaves (K, ...)) into one.
@@ -287,8 +297,10 @@ def aggregate_stack(grads, mesh, par: ParallelConfig,
                          for e in spec[1:]]
                 g = jax.lax.with_sharding_constraint(
                     g, NamedSharding(mesh, P("pod", None, *inner)))
-            pod_est = _mm_axis0(jnp.moveaxis(g, 0, 1), par.agg_num_iters,
-                                par.use_kernel)
+            else:
+                inner = []
+            pod_est = _mm_axis0_laid_out(jnp.moveaxis(g, 0, 1),
+                                         P(None, "pod", *inner), mesh, par)
             est = jnp.mean(pod_est, axis=0)
         else:
             g = leaf.astype(jnp.float32)
@@ -301,7 +313,7 @@ def aggregate_stack(grads, mesh, par: ParallelConfig,
             else:
                 raise ValueError(f"unknown aggregation {method!r}")
             g = jax.lax.with_sharding_constraint(g, NamedSharding(mesh, spec))
-            est = _mm_axis0(g, par.agg_num_iters, par.use_kernel)
+            est = _mm_axis0_laid_out(g, spec, mesh, par)
         est = est.astype(leaf.dtype)
         return jax.lax.with_sharding_constraint(
             est, NamedSharding(mesh, ospec))
@@ -473,8 +485,6 @@ def constrain_auto(x, spec: P):
     (observed: full 3.9 GiB expert tensors per device on dbrx)."""
     if all(e is None for e in spec):
         return x
-    if not compat.SUPPORTS_NESTED_MANUAL:
-        return x  # legacy jax: partial-auto constraints unsupported
     am = compat.get_abstract_mesh()
     return jax.lax.with_sharding_constraint(x, NamedSharding(am, spec))
 
@@ -486,11 +496,7 @@ def _model_manual(fn, in_spec: P, out_spec: P):
     directly on auto-sharded operands force SPMD to first all-gather the
     model axis -- observed as full 3.9 GiB per-device expert tensors on
     dbrx.  Running them inside a nested model-manual region keeps every
-    buffer model-sharded end to end.  Legacy jax cannot nest a manual
-    region, so the wrapper degrades to identity there (correct, just
-    without the memory win)."""
-    if not compat.SUPPORTS_NESTED_MANUAL:
-        return fn
+    buffer model-sharded end to end."""
     am = compat.get_abstract_mesh()
     if am is None or am.shape.get("model", 1) <= 1:
         return fn
@@ -650,7 +656,7 @@ def make_train_step_fsdp(model_cfg: ModelConfig, par: ParallelConfig,
     a = ax if len(ax) > 1 else ax[0]
 
     def local_step(params, opt_state, batch):
-        with sharding.use_mesh(mesh, {"batch": (), "fsdp": ()}, manual_region=True):
+        with sharding.use_mesh(mesh, {"batch": (), "fsdp": ()}):
             # local batch may be smaller than the configured microbatch
             # count on bigger meshes (e.g. 256/32 agents = 8 local seqs)
             nm = min(par.microbatches, jax.tree.leaves(batch)[0].shape[0])
@@ -792,7 +798,7 @@ def make_prefill_step(model_cfg: ModelConfig, mesh, *, fsdp: bool = False,
     bspecs = batch_specs(batch_template, mesh)
 
     def local(params, batch):
-        with sharding.use_mesh(mesh, {"batch": (), "fsdp": ()}, manual_region=True):
+        with sharding.use_mesh(mesh, {"batch": (), "fsdp": ()}):
             return M.prefill(params, model_cfg, batch, layer_hook=hook,
                              remat=False)
 
@@ -822,7 +828,7 @@ def make_decode_step(model_cfg: ModelConfig, mesh, *, fsdp: bool = False,
     tok_spec = P(a) if global_batch % num_agents(mesh) == 0 else P(None)
 
     def local(params, tokens, cache):
-        with sharding.use_mesh(mesh, {"batch": (), "fsdp": ()}, manual_region=True):
+        with sharding.use_mesh(mesh, {"batch": (), "fsdp": ()}):
             logits, cache = M.decode_step(params, model_cfg, tokens, cache,
                                           layer_hook=hook)
             next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -5,7 +5,8 @@ exercised via dryrun.py).
       --steps 100 --aggregation rs_mm --malicious 1 --attack additive
 
 Uses the reduced smoke config by default (CPU container); --full-config
-loads the assigned full architecture (only sensible on a real cluster).
+loads the assigned full architecture at its published widths, and
+--layers cuts its depth to whole layers so it fits one chip.
 Simulates the paper's Byzantine agents as data-parallel ranks whose
 gradients are corrupted before aggregation.  ``--agents K`` simulates K
 aggregation agents on however many devices exist (the sharding
@@ -31,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import compat, configs
 from repro.checkpoint import checkpoint as ckpt
 from repro.core import attacks
 from repro.data import synthetic
@@ -48,6 +49,9 @@ def build(args):
     else:
         model = configs.load_smoke(args.arch)
     if args.layers:
+        if args.full_config and args.layers < model.num_layers:
+            print(f"# depth cut: {args.layers} of {model.num_layers} "
+                  "layers (whole layers; widths as published)")
         model = dataclasses.replace(model, num_layers=args.layers)
     if args.d_model:
         # keep head structure consistent when scaling width
@@ -163,13 +167,8 @@ def main(argv=None):
     stream = synthetic.token_batches(synthetic.TokenStreamConfig(
         vocab_size=model.vocab_size, seq_len=args.seq, batch_size=batch))
 
-    print(f"# arch={model.name} params={n_params/1e6:.1f}M agents={k} "
-          f"agg={par.aggregation} malicious={args.malicious}")
-    t0 = time.time()
-    losses = []
-    for i in range(args.steps):
-        hb = next(stream)
-        jb = {"tokens": jnp.asarray(hb["tokens"])}
+    def device_batch(i):
+        jb = {"tokens": jnp.asarray(next(stream)["tokens"])}
         if model.arch_type == "vlm":
             jb["prefix"] = jnp.zeros(
                 (batch, model.num_prefix_tokens, model.d_model),
@@ -179,13 +178,33 @@ def main(argv=None):
                 jax.random.fold_in(jax.random.key(1), i),
                 (batch, model.num_prefix_tokens, model.d_model),
                 jnp.dtype(model.act_dtype))
+        return jb
+
+    dev = mesh.devices.flat[0]
+    print(f"# arch={model.name} params={n_params/1e6:.1f}M agents={k} "
+          f"agg={par.aggregation} kernel={par.use_kernel} "
+          f"malicious={args.malicious} batch={batch}x{args.seq} "
+          f"mesh={dict(mesh.shape)} on {mesh.devices.size} "
+          f"{dev.platform} device(s)")
+    jb = device_batch(0)
+    t0 = time.perf_counter()
+    step = step.lower(params, opt, jb).compile()
+    print(f"# step compile {time.perf_counter() - t0:.2f}s", flush=True)
+    losses, step_s = [], []
+    for i in range(args.steps):
+        if i:
+            jb = device_batch(i)
+        t0 = time.perf_counter()
         params, opt, metrics = step(params, opt, jb)
-        losses.append(float(metrics["loss"]))
+        losses.append(float(metrics["loss"]))     # waits for the step
+        step_s.append(time.perf_counter() - t0)
         if i % args.log_every == 0 or i == args.steps - 1:
-            dt = (time.time() - t0) / (i + 1)
             print(f"step {i:5d} loss {losses[-1]:.4f} "
                   f"gnorm {float(metrics['grad_norm']):.3f} "
-                  f"{dt*1e3:.0f} ms/step", flush=True)
+                  f"{step_s[-1]*1e3:.1f} ms", flush=True)
+    if len(step_s) > 1:
+        print(f"# steady step {np.median(step_s[1:])*1e3:.1f} ms "
+              f"(median of steps 1..{len(step_s) - 1})")
     if args.checkpoint:
         ckpt.save(args.checkpoint, params, step=args.steps)
         print(f"# saved {args.checkpoint}")
@@ -195,4 +214,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compat.enable_persistent_compilation_cache()
     main()

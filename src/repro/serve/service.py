@@ -36,8 +36,10 @@ Fault tolerance by construction:
     thrown out by the redescending loss -- the adaptive-weighting idea
     of Munoz-Gonzalez et al. (1909.05125) applied at admission time, so
     persistent byzantine senders stop costing kernel work at all;
-  * engine-launch failures are retried under ``retry.RetryPolicy``;
-    exhaustion degrades to carry-forward -- the loop never raises;
+  * injected engine-launch faults (``chaos.FaultInjected``) are retried
+    under ``retry.RetryPolicy``; exhaustion degrades to carry-forward.
+    Any other launch error -- a real device or runtime failure --
+    propagates: it is never disguised as a carried-forward round;
   * graceful degradation below ``k_min`` (the ladder, see
     docs/serving.md) and a trust-region step clip on every commit;
   * **crash recovery**: with a ``serve.journal.Journal`` attached,
@@ -61,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import mm_aggregate, ops, tuning
+from repro.serve import chaos as _chaos
 from repro.serve import journal as _journal
 from repro.serve import retry as _retry
 from repro.serve.buffer import AgentUpdate, CohortBuffer, Pending
@@ -776,5 +779,6 @@ class AggregationService:
 
         (result, wall), attempts = _retry.call(
             attempt, policy=self.config.retry, clock=self.clock,
-            rng=self._rng, on_retry=on_retry)
+            rng=self._rng, retryable=(_chaos.FaultInjected,),
+            on_retry=on_retry)
         return result, wall, attempts, cache_hit, compile_s

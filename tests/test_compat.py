@@ -1,7 +1,7 @@
-"""Direct coverage of the ``repro.compat`` shims: both the legacy and
-the modern branch of every helper, exercised in one interpreter by
-monkeypatching the HAS_* capability flags and stubbing the API surface
-the resident jax line does not ship."""
+"""Direct coverage of the ``repro.compat`` helpers: the compile-cache
+switch and the thin wrappers over the jax sharding API, checked against
+the installed jax line (stubbing ``jax`` entry points where a call's
+arguments are what is under test)."""
 
 import jax
 import jax.numpy as jnp
@@ -12,50 +12,56 @@ from repro import compat
 
 
 # ===========================================================================
-# capability flags
+# the installed API
 # ===========================================================================
 
 def test_flags_reflect_the_resident_api():
-    assert compat.HAS_MODERN_SHARD_MAP == hasattr(jax, "shard_map")
-    assert compat.HAS_AXIS_TYPE == hasattr(jax.sharding, "AxisType")
-    assert compat.HAS_ABSTRACT_MESH == hasattr(jax.sharding,
-                                               "get_abstract_mesh")
-    assert compat.SUPPORTS_NESTED_MANUAL == (
-        compat.HAS_MODERN_SHARD_MAP and compat.HAS_ABSTRACT_MESH)
+    # the helpers call these directly: no version branch remains
+    assert callable(jax.shard_map)
+    assert jax.sharding.AxisType.Auto is not None
+    assert callable(jax.sharding.get_abstract_mesh)
+    assert not any(name.startswith(("HAS_", "SUPPORTS_"))
+                   for name in vars(compat))
 
 
 # ===========================================================================
-# persistent compilation cache (env-guarded)
+# persistent compilation cache
 # ===========================================================================
 
-def test_compilation_cache_disabled_when_env_unset(monkeypatch):
+@pytest.fixture
+def restore_cache_config():
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    old = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in old.items():
+        jax.config.update(n, v)
+
+
+def test_compilation_cache_disabled_when_env_unset(monkeypatch,
+                                                   restore_cache_config):
+    # unset env -> the fixed in-checkout directory, never a temp path
     monkeypatch.delenv(compat.COMPILATION_CACHE_ENV, raising=False)
-    assert compat.enable_persistent_compilation_cache() is None
+    import pathlib
+    repo = pathlib.Path(compat.__file__).resolve().parents[2]
+    want = str(repo / ".jax_compile_cache")
+    assert compat.DEFAULT_COMPILATION_CACHE_DIR == want
+    assert compat.enable_persistent_compilation_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert ".jax_compile_cache/" in (repo / ".gitignore").read_text()
 
 
-def test_compilation_cache_points_jax_at_the_env_dir(tmp_path, monkeypatch):
-    old = jax.config.jax_compilation_cache_dir
+def test_compilation_cache_points_jax_at_the_env_dir(tmp_path, monkeypatch,
+                                                     restore_cache_config):
     monkeypatch.setenv(compat.COMPILATION_CACHE_ENV, str(tmp_path))
-    try:
-        assert compat.enable_persistent_compilation_cache() == str(tmp_path)
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
-
-
-def test_compilation_cache_tolerates_unknown_config(monkeypatch, tmp_path):
-    # older jax lines miss some knob names: best effort, never fatal
-    monkeypatch.setenv(compat.COMPILATION_CACHE_ENV, str(tmp_path))
-
-    def flaky_update(name, value):
-        raise AttributeError(name)
-
-    monkeypatch.setattr(jax.config, "update", flaky_update)
     assert compat.enable_persistent_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
 
 
 # ===========================================================================
-# make_mesh on both lines
+# make_mesh
 # ===========================================================================
 
 class _Recorder:
@@ -68,27 +74,13 @@ class _Recorder:
         return self.result
 
 
-def test_make_mesh_legacy_passes_no_axis_types(monkeypatch):
-    rec = _Recorder(result="mesh")
-    monkeypatch.setattr(compat, "HAS_AXIS_TYPE", False)
-    monkeypatch.setattr(jax, "make_mesh", rec)
-    assert compat.make_mesh((1,), ("agents",)) == "mesh"
-    ((args, kwargs),) = rec.calls
-    assert args == ((1,), ("agents",))
-    assert "axis_types" not in kwargs
-
-
 def test_make_mesh_modern_requests_all_auto_axes(monkeypatch):
-    class _AxisType:
-        Auto = "auto"
-
     rec = _Recorder(result="mesh")
-    monkeypatch.setattr(compat, "HAS_AXIS_TYPE", True)
     monkeypatch.setattr(jax, "make_mesh", rec)
-    monkeypatch.setattr(jax.sharding, "AxisType", _AxisType, raising=False)
     assert compat.make_mesh((1, 1), ("agents", "model")) == "mesh"
-    ((_, kwargs),) = rec.calls
-    assert kwargs["axis_types"] == ("auto", "auto")
+    ((args, kwargs),) = rec.calls
+    assert args == ((1, 1), ("agents", "model"))
+    assert kwargs["axis_types"] == (jax.sharding.AxisType.Auto,) * 2
 
 
 def test_make_mesh_live_branch_builds_a_real_mesh():
@@ -97,7 +89,7 @@ def test_make_mesh_live_branch_builds_a_real_mesh():
 
 
 # ===========================================================================
-# get_abstract_mesh on both lines
+# get_abstract_mesh
 # ===========================================================================
 
 def test_abstract_mesh_modern_branch(monkeypatch):
@@ -105,35 +97,31 @@ def test_abstract_mesh_modern_branch(monkeypatch):
         def __init__(self, shape):
             self.shape = shape
 
-    monkeypatch.setattr(compat, "HAS_ABSTRACT_MESH", True)
     monkeypatch.setattr(jax.sharding, "get_abstract_mesh",
-                        lambda: _FakeMesh({}), raising=False)
+                        lambda: _FakeMesh({}))
     assert compat.get_abstract_mesh() is None      # empty mesh -> None
 
     full = _FakeMesh({"agents": 2})
-    monkeypatch.setattr(jax.sharding, "get_abstract_mesh",
-                        lambda: full, raising=False)
+    monkeypatch.setattr(jax.sharding, "get_abstract_mesh", lambda: full)
     assert compat.get_abstract_mesh() is full
 
 
-def test_abstract_mesh_legacy_branch(monkeypatch):
-    monkeypatch.setattr(compat, "HAS_ABSTRACT_MESH", False)
+def test_abstract_mesh_tracks_the_active_mesh():
     assert compat.get_abstract_mesh() is None      # no active mesh
     mesh = compat.make_mesh((1,), ("agents",))
-    with mesh:
+    with jax.set_mesh(mesh):
         got = compat.get_abstract_mesh()
         assert got is not None and dict(got.shape) == {"agents": 1}
     assert compat.get_abstract_mesh() is None
 
 
 # ===========================================================================
-# shard_map on both lines
+# shard_map
 # ===========================================================================
 
 def test_shard_map_modern_kwarg_translation(monkeypatch):
     rec = _Recorder(result="wrapped")
-    monkeypatch.setattr(compat, "HAS_MODERN_SHARD_MAP", True)
-    monkeypatch.setattr(jax, "shard_map", rec, raising=False)
+    monkeypatch.setattr(jax, "shard_map", rec)
 
     fn = lambda x: x  # noqa: E731
     assert compat.shard_map(fn, in_specs="i", out_specs="o",
@@ -151,30 +139,10 @@ def test_shard_map_modern_kwarg_translation(monkeypatch):
     assert "axis_names" not in kwargs
 
 
-def test_shard_map_legacy_requires_a_concrete_mesh(monkeypatch):
-    monkeypatch.setattr(compat, "HAS_MODERN_SHARD_MAP", False)
-    monkeypatch.setattr(compat, "HAS_ABSTRACT_MESH", False)
-    with pytest.raises(ValueError, match="concrete mesh"):
-        compat.shard_map(lambda x: x, in_specs=None, out_specs=None)
-
-
-def test_shard_map_legacy_executes(monkeypatch):
-    monkeypatch.setattr(compat, "HAS_MODERN_SHARD_MAP", False)
+def test_shard_map_executes_on_a_real_mesh():
     P = jax.sharding.PartitionSpec
     mesh = compat.make_mesh((1,), ("agents",))
     wrapped = compat.shard_map(lambda x: x * 2, mesh=mesh,
                                in_specs=P("agents"), out_specs=P("agents"))
     out = wrapped(jnp.arange(4, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(out), [0.0, 2.0, 4.0, 6.0])
-
-
-def test_shard_map_legacy_resolves_the_active_mesh(monkeypatch):
-    monkeypatch.setattr(compat, "HAS_MODERN_SHARD_MAP", False)
-    monkeypatch.setattr(compat, "HAS_ABSTRACT_MESH", False)
-    P = jax.sharding.PartitionSpec
-    mesh = compat.make_mesh((1,), ("agents",))
-    with mesh:
-        wrapped = compat.shard_map(lambda x: x + 1, in_specs=P(),
-                                   out_specs=P())
-        out = wrapped(jnp.zeros(3))
-    np.testing.assert_allclose(np.asarray(out), np.ones(3))

@@ -274,6 +274,18 @@ def test_kernel_clean_under_debug_nans():
         jax.config.update("jax_debug_nans", False)
 
 
+@pytest.mark.parametrize("path", ["single", "two_pass"])
+@pytest.mark.parametrize("k", [3, 6, 7, 12, 24])
+def test_uniform_weight_ties_match_oracle(k, path):
+    """Uniform weights put the cumulative-weight crossing exactly on 1/2
+    at even K: the kernel's prefix scan must pick the same row as the
+    oracle's cumsum, at odd and even K, on both paths."""
+    x = jax.random.normal(jax.random.key(k), (k, 300))
+    a = jnp.ones((k,))
+    got = K.mm_aggregate_2d(x, a, interpret=True, path=path)
+    np.testing.assert_allclose(got, ref.mm_aggregate_ref(x, a), atol=1e-5)
+
+
 def test_zero_weights_fall_back_to_uniform():
     """All-zero (or negative-sum) weights must not NaN: the engine falls
     back to uniform combination weights."""

@@ -158,6 +158,7 @@ def test_scenarios_import_stays_light():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"      # a child never competes for a chip
     out = sp_.run([sys.executable, "-c",
                    "import sys, repro.scenarios; "
                    "assert 'repro.models.model' not in sys.modules; "
@@ -320,6 +321,7 @@ def test_scenario_sweep_substrate_smoke_cli():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"      # a child never competes for a chip
     out = subprocess.run(
         [sys.executable, os.path.join(root, "examples", "scenario_sweep.py"),
          "--paradigm", "substrate", "--smoke"],

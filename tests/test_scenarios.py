@@ -395,6 +395,7 @@ def test_sharded_collective_matches_stacked():
     """)
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"      # a child never competes for a chip
     out = subprocess.run([sys.executable, "-c", script], cwd=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), env=env,
         capture_output=True, text=True, timeout=600)

@@ -270,6 +270,26 @@ def test_launch_fault_exhaustion_degrades_but_service_lives():
     assert c2.kind == "aggregated"
 
 
+def test_real_launch_error_propagates_instead_of_carrying_forward():
+    """Only injected faults are retried: a device or runtime error is
+    raised to the caller, never turned into a carried-forward round."""
+    calls = {"n": 0}
+
+    def hook():
+        calls["n"] += 1
+        raise RuntimeError("device lost")
+
+    svc = ssvc.AggregationService(
+        np.zeros(DIM, np.float32),
+        config=ssvc.ServeConfig(k_min=4, backend="jnp"),
+        clock=SimClock(), fault_hook=hook)
+    with pytest.raises(RuntimeError, match="device lost"):
+        fill_full_cohort(svc, value=0.5)
+    assert calls["n"] == 1
+    assert svc.telemetry.counters["launch_failed"] == 0
+    assert svc.telemetry.counters["carried_forward"] == 0
+
+
 # ===========================================================================
 # chaos config + replay
 # ===========================================================================

@@ -99,6 +99,7 @@ SCRIPT = textwrap.dedent("""
 def dist():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"      # a child never competes for a chip
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=1800)
     assert proc.returncode == 0, proc.stderr[-3000:]

@@ -258,6 +258,29 @@ def test_autotune_caches_two_pass_winner_and_engine_consults():
         tuning.clear_cache()
 
 
+@pytest.mark.parametrize("k,dtype", [(20, jnp.float32), (32, jnp.float32),
+                                     (48, jnp.bfloat16), (64, jnp.bfloat16)])
+def test_candidate_k_splits_are_whole_sublane_tiles(k, dtype):
+    """A K block shorter than the padded K axis must be a multiple of
+    the vreg row tile (8 rows f32, 16 bf16) or Mosaic refuses it."""
+    rows = tuning.sublane_rows(dtype)
+    for bm, bk in tuning.candidate_blocks(k, 4096, 1, dtype):
+        assert bk is None or bk % rows == 0, (bm, bk)
+    splits = {bk for _, bk in tuning.candidate_blocks(k, 4096, 1, dtype)}
+    assert (None in splits) and (len(splits) == 2) == (k in (32, 64))
+
+
+def test_autotune_raises_on_a_refused_candidate():
+    tuning.clear_cache()
+    try:
+        with pytest.raises(ValueError, match="block_k"):
+            tuning.autotune(8, 256, 1, interpret=True, reps=1,
+                            candidates=((128, 3),))
+        assert tuning.cache_size() == 0
+    finally:
+        tuning.clear_cache()
+
+
 def test_candidate_choices_include_crossover_for_large_k():
     paths = {c.path for c in tuning.candidate_choices(256, 1 << 14, 1)}
     assert "two_pass" in paths
