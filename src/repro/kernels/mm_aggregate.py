@@ -106,6 +106,8 @@ _SCALE_FLOOR = 1e-12
 _MAD_CONSISTENCY = 1.4826022185056018
 
 PATHS = ("single", "two_pass")
+# the pallas_call's name per path: the Mosaic kernel's name in a profile
+KERNEL_NAMES = {"single": "mm_aggregate", "two_pass": "mm_aggregate_two_pass"}
 # conservative per-core VMEM budget for the kernel working set (the
 # full VMEM is ~16 MB; leave room for double buffering + output).  The
 # single source of truth for the heuristic lane tile (kernels.tuning)
@@ -747,6 +749,7 @@ def _launch(
         out_shape=call.out_shape,
         scratch_shapes=list(call.scratch_shapes),
         interpret=interpret,
+        name=KERNEL_NAMES[plan.path],
     )(xp, ap)
     return out[:, :m]
 

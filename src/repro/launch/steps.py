@@ -44,6 +44,12 @@ from repro.optim import optimizers
 # roots whose stacked leaves are scanned (and hence fsdp-hookable)
 SCAN_DIMS = {"blocks": 1, "enc_blocks": 1, "mamba_groups": 2}
 
+# ``jax.named_scope`` names of the Mode A step's phases, in order.  They
+# reach every compiled instruction's ``op_name`` metadata (and so the
+# profiler's op view); the backward of ``agent_grads`` carries
+# ``transpose(`` in its ``op_name``.
+STEP_PHASES = ("agent_grads", "attack", "aggregate", "optimizer")
+
 
 # ===========================================================================
 # parameter / optimizer / batch / cache specs
@@ -382,7 +388,6 @@ def make_train_step_gspmd(model_cfg: ModelConfig, par: ParallelConfig,
                 spec = P(ax if len(ax) > 1 else ax[0])
                 return jax.lax.with_sharding_constraint(
                     t, NamedSharding(mesh, spec))
-            ab = jax.tree.map(to_agents, batch)
 
             nm = par.microbatches
 
@@ -421,37 +426,44 @@ def make_train_step_gspmd(model_cfg: ModelConfig, par: ParallelConfig,
                 g = jax.tree.map(lambda t: t / nm_, gsum)
                 return jnp.mean(losses), g
 
-            losses, grads = jax.vmap(agent_grad)(ab)   # leaves: (K, ...)
+            with jax.named_scope("agent_grads"):
+                ab = jax.tree.map(to_agents, batch)
+                losses, grads = jax.vmap(agent_grad)(ab)  # leaves: (K, ...)
 
-            # keep the per-agent stacks K-sharded over the agent axes and
-            # model-sharded like their params (SPMD would otherwise
-            # replicate the (K, full-param) f32 stacks).
-            a_entry = ax if len(ax) > 1 else ax[0]
-            g_leaves, g_def = jax.tree.flatten(grads)
-            sp_leaves = jax.tree.leaves(
-                pspecs, is_leaf=lambda x: isinstance(x, P))
-            g_leaves = [
-                jax.lax.with_sharding_constraint(
-                    g, NamedSharding(mesh, P(a_entry, *sp)))
-                for g, sp in zip(g_leaves, sp_leaves)]
-            grads = jax.tree.unflatten(g_def, g_leaves)
+                # keep the per-agent stacks K-sharded over the agent axes
+                # and model-sharded like their params (SPMD would
+                # otherwise replicate the (K, full-param) f32 stacks).
+                a_entry = ax if len(ax) > 1 else ax[0]
+                g_leaves, g_def = jax.tree.flatten(grads)
+                sp_leaves = jax.tree.leaves(
+                    pspecs, is_leaf=lambda x: isinstance(x, P))
+                g_leaves = [
+                    jax.lax.with_sharding_constraint(
+                        g, NamedSharding(mesh, P(a_entry, *sp)))
+                    for g, sp in zip(g_leaves, sp_leaves)]
+                grads = jax.tree.unflatten(g_def, g_leaves)
 
             if byzantine is not None and byzantine.num_malicious > 0:
-                key = jax.random.fold_in(jax.random.key(17), opt_state.step)
-                grads = byzantine.apply_tree(grads, key, opt_state.step)
+                with jax.named_scope("attack"):
+                    key = jax.random.fold_in(jax.random.key(17),
+                                             opt_state.step)
+                    grads = byzantine.apply_tree(grads, key, opt_state.step)
 
-            agg = aggregate_stack(grads, mesh, par, pspecs, ax)
-            new_params, new_opt = optimizers.update(opt_cfg, params, agg,
-                                                    opt_state)
-            metrics = {"loss": jnp.mean(losses),
-                       "grad_norm": optimizers.global_norm(agg)}
-            if consensus_metric:
-                if byzantine is not None and byzantine.num_malicious > 0:
-                    benign = ~byzantine.malicious_mask(k_agents,
-                                                       opt_state.step)
-                else:
-                    benign = jnp.ones((k_agents,), bool)
-                metrics["consensus"] = grad_consensus(grads, benign)
+            with jax.named_scope("aggregate"):
+                agg = aggregate_stack(grads, mesh, par, pspecs, ax)
+
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizers.update(opt_cfg, params, agg,
+                                                        opt_state)
+                metrics = {"loss": jnp.mean(losses),
+                           "grad_norm": optimizers.global_norm(agg)}
+                if consensus_metric:
+                    if byzantine is not None and byzantine.num_malicious > 0:
+                        benign = ~byzantine.malicious_mask(k_agents,
+                                                           opt_state.step)
+                    else:
+                        benign = jnp.ones((k_agents,), bool)
+                    metrics["consensus"] = grad_consensus(grads, benign)
             return new_params, new_opt, metrics
 
     return step, pspecs

@@ -188,8 +188,12 @@ def main(argv=None):
           f"{dev.platform} device(s)")
     jb = device_batch(0)
     t0 = time.perf_counter()
-    step = step.lower(params, opt, jb).compile()
-    print(f"# step compile {time.perf_counter() - t0:.2f}s", flush=True)
+    with compat.compile_clock() as clock:
+        step = step.lower(params, opt, jb).compile()
+    print(f"# step compile {time.perf_counter() - t0:.2f}s (trace "
+          f"{clock['trace_s']:.2f}s, lower {clock['lower_s']:.2f}s, "
+          f"compile or cache load {clock['compile_s']:.2f}s, "
+          f"{clock['cache_hits']} cache hit(s))", flush=True)
     losses, step_s = [], []
     for i in range(args.steps):
         if i:

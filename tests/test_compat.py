@@ -1,7 +1,10 @@
 """Direct coverage of the ``repro.compat`` helpers: the compile-cache
-switch and the thin wrappers over the jax sharding API, checked against
-the installed jax line (stubbing ``jax`` entry points where a call's
-arguments are what is under test)."""
+switch, the compile clock and the thin wrappers over the jax sharding
+API, checked against the installed jax line (stubbing ``jax`` entry
+points where a call's arguments are what is under test)."""
+
+import contextlib
+import time
 
 import jax
 import jax.numpy as jnp
@@ -146,3 +149,79 @@ def test_shard_map_executes_on_a_real_mesh():
                                in_specs=P("agents"), out_specs=P("agents"))
     out = wrapped(jnp.arange(4, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(out), [0.0, 2.0, 4.0, 6.0])
+
+
+# ===========================================================================
+# compile_clock
+# ===========================================================================
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_compile_clock_every_nested_scope_sees_the_compile(depth):
+    f = jax.jit(lambda x: jnp.cos(x) * depth)     # fresh: never compiled
+    x = jnp.ones(5)
+    with contextlib.ExitStack() as stack:
+        clocks = [stack.enter_context(compat.compile_clock())
+                  for _ in range(depth)]
+        t0 = time.perf_counter()
+        f(x).block_until_ready()
+        wall = time.perf_counter() - t0
+    for c in clocks:
+        assert c["compiles"] >= 1 and c["trace_s"] > 0
+        assert c["cache_hits"] >= 0
+        # no interval counted twice: the parts fit in the wall time
+        assert c["trace_s"] + c["lower_s"] + c["compile_s"] <= wall
+        assert c == clocks[0]
+    with compat.compile_clock() as again:
+        f(x).block_until_ready()
+    assert again == {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+                     "compiles": 0, "cache_hits": 0}
+
+
+def test_compile_clock_scope_is_removed_by_identity():
+    with compat.compile_clock():
+        twin = compat._ACTIVE_CLOCKS[-1]
+        with compat.compile_clock():
+            # both scopes hold equal (empty) event lists here
+            assert compat._ACTIVE_CLOCKS[-1] == twin
+        assert compat._ACTIVE_CLOCKS[-1] is twin
+        assert len([c for c in compat._ACTIVE_CLOCKS if c is twin]) == 1
+    assert not any(c is twin for c in compat._ACTIVE_CLOCKS)
+
+
+def test_compile_clock_splits_nested_traces_without_double_counting():
+    # spans: trace [0, 4] holds a nested trace [1, 2]; lower [3, 6]
+    # overlaps the trace; compile [6, 9]
+    events = [("trace_s", 1.0, 2.0), ("trace_s", 0.0, 4.0),
+              ("lower_s", 3.0, 6.0), ("compile_s", 6.0, 9.0)]
+    with compat.compile_clock() as c:
+        compat._ACTIVE_CLOCKS[-1].extend(events)
+    assert c["trace_s"] == 4.0 and c["lower_s"] == 2.0
+    assert c["compile_s"] == 3.0 and c["compiles"] == 1
+
+
+def test_compile_totals_count_each_function_by_its_name():
+    def totals_probe_fn(x):                 # a name no other test uses
+        return jnp.sin(x) + inner(x)
+
+    @jax.jit
+    def totals_probe_inner(x):
+        return x * 2.0
+    inner = totals_probe_inner
+    zeros = {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0, "compiles": 0}
+    assert compat.compile_totals("totals_probe_fn") == zeros
+    f = jax.jit(totals_probe_fn)
+    f.lower(jnp.ones(3)).compile()
+    once = compat.compile_totals("totals_probe_fn")
+    assert once["compiles"] == 1
+    assert min(once["trace_s"], once["lower_s"], once["compile_s"]) > 0
+    # the jit it calls is traced inside it, and never compiled alone
+    inner_t = compat.compile_totals("totals_probe_inner")
+    assert 0 < inner_t["trace_s"] <= once["trace_s"]
+    assert inner_t["compiles"] == 0
+    f(jnp.ones(3)).block_until_ready()      # the same shapes: no compile
+    assert compat.compile_totals("totals_probe_fn")["compiles"] == 1
+    f(jnp.ones(4)).block_until_ready()      # new shapes: one more
+    assert compat.compile_totals("totals_probe_fn")["compiles"] == 2
+    # a copy: the caller cannot change the totals
+    compat.compile_totals("totals_probe_fn")["compiles"] = 99
+    assert compat.compile_totals("totals_probe_fn")["compiles"] == 2

@@ -14,13 +14,14 @@ in every worker.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ops, tuning
+from repro.kernels import mm_aggregate, ops, tuning
 
 V5E_HBM_BYTES = 16 * 2 ** 30
 
@@ -67,7 +68,11 @@ def _compile(sharding, k, m, n=None, *, dtype=jnp.float32, weighted=False,
         fn, args = eng.aggregate, (x,)
     with ops.record_workloads() as rec:
         compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's instruction carries its stable name, as a profile shows
+    name = mm_aggregate.KERNEL_NAMES[rec[0]["path"]]
+    assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", text), name
     ma = compiled.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes)
