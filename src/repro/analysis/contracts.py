@@ -6,8 +6,10 @@ configuration (``mm_aggregate.kernel_call`` -- the same object
 executing anything:
 
   one-residency   the input BlockSpec index map, enumerated over the
-                  whole grid, fetches each (K, bm) update tile exactly
-                  once, the fetch count equals the plan's modeled
+                  whole grid, fetches each (K block, tile) update block
+                  exactly once -- (bk, bR, bC) blocks of the single-pass
+                  (K, R, C) view, (bk, bm) blocks of the two-pass (K, M)
+                  operand -- the fetch count equals the plan's modeled
                   ``input_block_fetches``, and re-deriving the plan at a
                   different N leaves the input traffic unchanged
                   (N-independence -- the N axis must never re-enter the
@@ -16,7 +18,9 @@ executing anything:
                   re-fetches another program's tile); the output map
                   writes each (N, bm) tile from the M grid axis only
                   (K steps revisit the same tile -- the accumulation
-                  pattern -- but never two different tiles).
+                  pattern -- but never two different tiles); the
+                  weights are one residency (a whole-array SMEM operand,
+                  or the single zero block).
   vmem-model      the declared VMEM scratch buffers match the modeled
                   working set: the residency + two-pass stat buffers
                   are exactly the modeled terms, the total is within
@@ -69,6 +73,21 @@ def _where(plan: mk.LaunchPlan) -> str:
             f"/{plan.path}/bm{plan.block_m}_bk{plan.block_k}")
 
 
+def _blocks(plan: mk.LaunchPlan) -> Tuple[tuple, tuple, tuple]:
+    """The planned (input block, output block, output array) shapes."""
+    if plan.path == "two_pass":
+        return ((plan.block_k, plan.block_m), (plan.n_out, plan.block_m),
+                (plan.n_out, plan.m_total))
+    tile = (plan.block_rows, plan.block_cols)
+    return ((plan.block_k,) + tile, (plan.n_out,) + tile,
+            (plan.n_out, plan.rows, plan.cols))
+
+
+def _plan_shape(plan: mk.LaunchPlan) -> Optional[tuple]:
+    """A per-agent shape that re-derives the plan's view."""
+    return (plan.rows, plan.cols) if plan.view == "rows" else None
+
+
 def audit_call(plan: mk.LaunchPlan, call: mk.KernelCall,
                *, dtype="float32") -> List[Finding]:
     """Audit one realized kernel configuration against its plan."""
@@ -100,25 +119,28 @@ def audit_call(plan: mk.LaunchPlan, call: mk.KernelCall,
         dup = n_fetches - len(distinct)
         finding("one-residency",
                 f"{dup} input-tile re-fetches: the grid -> input-tile "
-                "map is not injective, so some (K, bm) tile is streamed "
+                "map is not injective, so some update block is streamed "
                 "from HBM more than once per launch", ident="refetch")
-    expected_tiles = {(ki, mi) for mi, ki in _grid_points(call.grid)}
+    expected_tiles = {(ki,) + plan.tile(mi)
+                      for mi, ki in _grid_points(call.grid)}
     if distinct != expected_tiles:
         finding("one-residency",
                 "input index map does not cover each (K block, M block) "
                 "tile exactly once (missing or out-of-range tiles)",
                 ident="coverage")
-    if tuple(x_spec.block_shape) != (plan.block_k, plan.block_m):
+    x_block, o_block, out_array = _blocks(plan)
+    if tuple(x_spec.block_shape) != x_block:
         finding("one-residency",
                 f"input block shape {tuple(x_spec.block_shape)} != "
-                f"planned ({plan.block_k}, {plan.block_m})",
+                f"planned {x_block}",
                 ident="block-shape")
 
     # --- N-independence: input traffic must not scale with N ---
     alt_n = plan.n_out * 4 + 1
     alt = mk.launch_plan(plan.k_pad, plan.m_total, alt_n, dtype=dtype,
                          block_m=plan.block_m,
-                         block_k=plan.block_k, path=plan.path)
+                         block_k=plan.block_k, path=plan.path,
+                         shape=_plan_shape(plan))
     if alt.input_block_fetches != plan.input_block_fetches or \
             alt.grid != plan.grid:
         finding("n-independence",
@@ -129,15 +151,16 @@ def audit_call(plan: mk.LaunchPlan, call: mk.KernelCall,
 
     # --- weight operand: one broadcast residency, not per-step slices ---
     a_spec = call.in_specs[1]
-    a_tiles = {tuple(a_spec.index_map(mi, ki))
-               for mi, ki in _grid_points(call.grid)}
-    if a_tiles != {(0, 0)}:
-        finding("one-residency",
-                f"weight index map addresses tiles {sorted(a_tiles)}; "
-                "expected the single broadcast (0, 0) residency",
-                ident="weights")
+    if a_spec.index_map is not None:      # None: the whole array, once
+        a_tiles = {tuple(a_spec.index_map(mi, ki))
+                   for mi, ki in _grid_points(call.grid)}
+        if len(a_tiles) != 1 or any(next(iter(a_tiles))):
+            finding("one-residency",
+                    f"weight index map addresses tiles {sorted(a_tiles)};"
+                    " expected the single broadcast zero-block residency",
+                    ident="weights")
 
-    # --- output surface: one (N, bm) tile per M index, M-axis only ---
+    # --- output surface: one (N, tile) block per M index, M-axis only ---
     o_tiles = {}
     for mi, ki in _grid_points(call.grid):
         o_tiles.setdefault(mi, set()).add(tuple(call.out_specs.index_map(mi, ki)))
@@ -163,7 +186,7 @@ def audit_call(plan: mk.LaunchPlan, call: mk.KernelCall,
                 "HBM write is the (N, M) estimate -- per-K-block stats "
                 "must stay in VMEM scratch or the <= 2x traffic bound "
                 "breaks")
-    expected_out = (plan.n_out, plan.m_total)
+    expected_out = out_array
     stats_shape = (plan.num_k_blocks, plan.n_out, plan.block_m)
     for s in shapes:
         if tuple(s.shape) == stats_shape and plan.path == "two_pass":
@@ -176,8 +199,13 @@ def audit_call(plan: mk.LaunchPlan, call: mk.KernelCall,
                     f"unexpected HBM output shape {tuple(s.shape)}; "
                     f"the estimate is {expected_out}", ident="extra-output")
 
+    if tuple(call.out_specs.block_shape) != o_block:
+        finding("output-map",
+                f"output block shape {tuple(call.out_specs.block_shape)} "
+                f"!= planned {o_block}")
+
     # --- VMEM model: declared scratch must match the modeled terms ---
-    residency = 4 * plan.k_pad * plan.block_m
+    residency = plan.residency_bytes
     expected_scratch = residency + plan.stats_bytes
     declared = call.scratch_bytes()
     if declared != expected_scratch:
@@ -189,8 +217,9 @@ def audit_call(plan: mk.LaunchPlan, call: mk.KernelCall,
         model = mk.two_pass_vmem_bytes(plan.k_pad, plan.n_out, plan.block_m,
                                        plan.block_k, plan.n_chunk)
     else:
-        model = mk.single_pass_vmem_bytes(plan.k_pad, plan.n_out,
-                                          plan.block_m)
+        model = mk.single_pass_vmem_bytes(
+            plan.k_pad, plan.n_out, plan.block_rows * plan.block_cols,
+            block_k=plan.block_k, itemsize=jnp.dtype(dtype).itemsize)
     if plan.vmem_bytes != model:
         finding("vmem-model",
                 f"plan.vmem_bytes {plan.vmem_bytes} != the "
@@ -204,11 +233,13 @@ def audit_call(plan: mk.LaunchPlan, call: mk.KernelCall,
     if plan.vmem_bytes > mk.VMEM_BUDGET_BYTES:
         # the one sanctioned overflow: a mesh below the two-pass
         # crossover whose single-pass model overflows even at the
-        # narrowest lane tile -- the engine keeps those single-pass for
+        # narrowest tile -- the engine keeps those single-pass for
         # bit-stability with the pre-two-pass kernel.  Anything else
         # means the resolver left budget on the table (a narrower tile
         # or the two-pass path would have fit).
-        narrow = mk.single_pass_vmem_bytes(plan.k_pad, plan.n_out, 128)
+        narrow = mk.single_pass_vmem_bytes(
+            plan.k_pad, plan.n_out, mk.LANE, block_k=plan.block_k,
+            itemsize=jnp.dtype(dtype).itemsize)
         forced_small_mesh = (plan.path == "single"
                              and plan.k_pad < mk._TWO_PASS_MIN_K
                              and narrow > mk.VMEM_BUDGET_BYTES)

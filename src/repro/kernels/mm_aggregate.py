@@ -11,53 +11,62 @@ combination weights a (Eq. 10/13; uniform a recovers Eq. 8):
 
 A naive jnp composition round-trips HBM ~3+T times (two sorts, T
 weighted reductions).  The kernel fuses *everything* into one VMEM
-residency per (K, bm) tile: the agent axis K is small (the mesh's data
-axis, <= 64 here), so a full tile of K rows x bm lanes sits in a few
-hundred KB of VMEM, and the whole estimate is computed before the tile
-is written back once.
+residency per tile and writes the estimate back once.
+
+SINGLE-PASS LAYOUT: the agent axis leads, coordinates fill whole vregs.
+Each (K, ...) operand is presented to the kernel as a (K, R, C) view
+(``leaf_view``): a leaf whose trailing matrix (A, B) has B % 128 == 0
+and A % 8 == 0 is read as its own matrix (R = prod(...) * A, C = B), a
+reshape that is a bitcast under the TPU's (8, 128) tiling, so no copy
+runs before or after the kernel; anything else is flattened and read as
+(M / 128, 128) rows, M padded with ZERO columns (a non-finite pad would
+make the in-kernel MAD compute inf - inf = nan).  The agent axis K is
+the leading, untiled axis of every block, so agent row ``x[k]`` is a
+dense (rows, lanes) slab: the sort network, the median, the MAD and the
+Tukey IRLS run on full (8, 128) vregs, and picking an agent's row costs
+no relayout.
 
 TPU adaptation notes (vs a GPU port):
   * No `sort` primitive is needed: K is *static*, so the median is a
-    bitonic sorting network (O(K log^2 K) compare-exchange passes of
-    min/max on sublane-reshaped registers) -- pure VPU ops, no
-    data-dependent control flow.  One shared network serves the plain
-    sort, the deviation (MAD) sort, and the weighted variant, which
-    carries all N weight planes through the value comparisons and
-    selects the cumulative-weight-0.5 crossing per plane.
-  * The network wants a power-of-two row count, so the sort operand is
-    topped up (in registers, never in HBM) with +inf sentinel rows of
-    weight 0; the median/MAD read fixed ranks (K-1)//2 and K//2 of the
-    sorted tile, so sentinels never enter.  IRLS masks sentinel rows
-    explicitly (0 * inf = nan otherwise).
-  * m is tiled in multiples of 128 lanes; the launcher pads M with ZERO
-    columns (sentinel +inf columns would make the in-kernel MAD compute
-    inf - inf = nan) and strips the pad.
+    bitonic sorting network over the list of agent slabs -- elementwise
+    min/max pairs, or a compare and selects when weight planes ride
+    along -- pure VPU ops, no data-dependent control flow.  The network
+    wants a power-of-two row count; the missing rows are +inf
+    sentinels of weight 0 that exist only at trace time, so every
+    compare-exchange against one is resolved statically and costs
+    nothing.
+  * The median/MAD read fixed ranks (K-1)//2 and K//2 of the sorted
+    slabs; the weighted median selects the cumulative-weight-0.5
+    crossing.  The IRLS multiplies by one reciprocal of c * scale per
+    coordinate instead of dividing per agent and sweep.
+  * Combination weights are per-agent scalars in SMEM, broadcast onto
+    the slabs; each of the N weight columns is estimated in turn from
+    the same resident slabs.
   * Compute is float32 internally regardless of input dtype (bf16
-    gradients upcast per tile, bf16 written back -- matches the
+    gradients upcast per slab, bf16 written back -- matches the
     reference).
 
 ONE-RESIDENCY BATCHING (grid and streaming).  The launch grid is
-(M_pad // bm, K_pad // bk): each (bk, bm) input block is DMA'd into a
-persistent (K_pad, bm) VMEM scratch accumulator, and on the last K step
-ALL N neighborhood estimates (the weight columns of a (K, N) combining
-matrix) are computed from that single residency.  The N axis lives in
-the kernel BODY, not the launch grid, so the number of HBM fetches of
-the update matrix is (M_pad/bm) * (K_pad/bk) -- independent of N.  The
-pre-batching kernel ran grid (N, M/bm, K/bk) and re-streamed the whole
-(K, M) matrix once per weight column: an N x traffic overhead for
-diffusion rounds (N = graph size).  ``launch_plan`` is the single
-source of truth for the grid/tile geometry and the modeled traffic; the
-benchmarks audit it.
+(tiles, K_pad // bk): a tile is (bR, bC) coordinates of the view, about
+``block_m`` of them, and each (bk, bR, bC) input block is fetched once.
+When K streams in several blocks they accumulate in a (K_pad, bR, bC)
+f32 VMEM scratch; on the last K step ALL N neighborhood estimates (the
+weight columns of a (K, N) combining matrix) are computed from that
+single residency, one (8, 128 * j) chunk of slabs at a time.  The N
+axis lives in the kernel BODY, not the launch grid, so the number of
+HBM fetches of the update matrix is tiles * (K_pad/bk) -- independent
+of N.  ``launch_plan`` is the single source of truth for the grid/tile
+geometry and the modeled traffic; the benchmarks audit it.
 
 Block sizes default to ``kernels.tuning`` (cached autotuner winner, or
 a VMEM-budget heuristic when no measurement is cached).
 
 TWO-PASS K-MAJOR PATH (K >> 64).  The single-pass kernel's working set
-is dominated by the full-K sort networks: the weighted-median carry
-planes and the MAD deviation planes are (next_pow2(K), N, bm) f32, so
-large K (and K x N) overflows VMEM.  ``path="two_pass"`` keeps the SAME
-(M/bm, K/bk) grid and single input residency but replaces the full-K
-sorts with two passes *over the K axis*:
+is dominated by the full-K sort networks: a chunk holds next_pow2(K)
+slabs of values, weight carries and deviations, so large K overflows
+VMEM.  ``path="two_pass"`` reads the flattened (K, M) operand in
+(bk, bm) blocks on an (M/bm, K/bk) grid with a single input residency,
+and replaces the full-K sorts with two passes *over the K axis*:
 
   pass 1 (every K grid step): the streamed (bk, bm) block is sorted by
       a bk-sized bitonic network (working set scales with bk, not K)
@@ -78,9 +87,8 @@ sorts with two passes *over the K axis*:
 
 The N axis is additionally processed in ``n_chunk`` column chunks so
 the transient sort/IRLS planes are (bk, n_chunk, bm) instead of
-(K, N, bm) -- the single-pass VMEM blow-up never re-enters through N.
-Input block fetches/bytes are identical to the single-pass plan at
-equal tile sizes (one residency, N-free grid), so total modeled HBM
+(K, N, bm).  Like the single-pass plan's, the input blocks are fetched
+once (one residency, N-free grid), so total modeled HBM
 traffic stays ~1x (bounded by 2x via K padding to bk multiples).
 ``launch_plan`` models both paths (geometry, traffic, VMEM residency)
 and auto-selects: two-pass iff K > 64 and the single-pass VMEM model
@@ -91,6 +99,7 @@ measured single<->two-pass crossover) takes precedence.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -125,6 +134,47 @@ _CHUNK_BUDGET_BYTES = 2 * 2 ** 20
 _MAX_N_CHUNKS = 16
 # rows per block of the weighted-median prefix scan (see _wquantile_planes)
 _SCAN_BLOCK = 16
+LANE = 128
+# single-pass tiles: at most this many lanes per block row, and one
+# compute chunk of at most this many coordinates (two f32 vregs)
+_MAX_BLOCK_COLS = 1024
+_CHUNK_COORDS = 2048
+
+
+def sublane_rows(dtype) -> int:
+    """Row tile of one vreg for ``dtype``: 8 rows for 32-bit, 16 for
+    16-bit.  A block's second-minor extent is a multiple of it (or the
+    whole axis), or Mosaic refuses the BlockSpec."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def leaf_view(shape) -> Tuple[str, int, int]:
+    """The (R, C) matrix the single-pass kernel reads one agent's
+    coordinates as, for a per-agent ``shape``: ``("rows", R, C)`` is the
+    leaf's own trailing matrix (B % 128 == 0, A % 8 == 0: the reshape is
+    a bitcast), ``("flat", R, 128)`` the flattened coordinates, M padded
+    with zeros to R * 128."""
+    shape = tuple(int(d) for d in shape)
+    if len(shape) >= 2 and shape[-1] % LANE == 0 and shape[-2] % 8 == 0:
+        return "rows", math.prod(shape[:-1]), shape[-1]
+    return "flat", max(1, -(-math.prod(shape) // LANE)), LANE
+
+
+def single_pass_tile(rows: int, cols: int, block_m: int,
+                     dtype=jnp.float32) -> Tuple[int, int]:
+    """(bR, bC) of one single-pass grid step: about ``block_m``
+    coordinates of the (R, C) view, at most ``_MAX_BLOCK_COLS`` lanes
+    wide, rows a whole number of sublane tiles (or the whole axis when
+    it is shorter than one).  Edge tiles may overhang the view: their
+    reads are unspecified and their writes dropped, and since every
+    coordinate is estimated on its own, the overhang touches nothing."""
+    sub = sublane_rows(dtype)
+    bc = min(cols, _MAX_BLOCK_COLS,
+             max(LANE, (block_m // sub) // LANE * LANE))
+    if rows <= sub:
+        return rows, bc
+    return min(rows // sub * sub,
+               max(sub, (block_m // bc) // sub * sub)), bc
 
 
 def next_pow2(n: int) -> int:
@@ -213,14 +263,6 @@ def _weight_planes(a: jnp.ndarray, bm: int) -> jnp.ndarray:
     return jnp.where(lane >= 0, a[:, :, None], 0.0)
 
 
-def _median_rows(x_sorted: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Median of the first k (valid) rows of an ascending-sorted tile whose
-    pad rows are +inf (and therefore sorted to the end)."""
-    lo = x_sorted[(k - 1) // 2]
-    hi = x_sorted[k // 2]
-    return 0.5 * (lo + hi)
-
-
 def _wquantile_planes(xs: jnp.ndarray, ws: jnp.ndarray, half) -> jnp.ndarray:
     """Weighted median crossings of an ascending-sorted tile.
 
@@ -260,12 +302,6 @@ def _wquantile_planes(xs: jnp.ndarray, ws: jnp.ndarray, half) -> jnp.ndarray:
     return jnp.sum(out, axis=0)
 
 
-def _weighted_median_planes(xs: jnp.ndarray, ws: jnp.ndarray) -> jnp.ndarray:
-    """Weighted medians of an ascending-sorted tile, one per (globally
-    normalized) weight plane: the cumulative-weight-1/2 crossing."""
-    return _wquantile_planes(xs, ws, 0.5)
-
-
 def _rank_median_planes(xs_sorted: jnp.ndarray, cnt) -> jnp.ndarray:
     """Midpoint median of the first ``cnt`` rows of an ascending-sorted
     tile whose pad rows are +inf.  ``cnt`` may be a traced scalar (the
@@ -277,66 +313,157 @@ def _rank_median_planes(xs_sorted: jnp.ndarray, cnt) -> jnp.ndarray:
     return 0.5 * (lo + hi)
 
 
-def _mm_kernel(x_ref, a_ref, o_ref, xs_ref, *, k: int, block_k: int,
-               num_iters: int, c: float, weighted: bool):
-    """Grid (M/bm, K_pad/bk): stream K blocks into the VMEM scratch
-    accumulator; on the last K step compute ALL N estimates from that
-    one residency (the N axis is a kernel-body batch, not a grid axis).
+def _sort_slabs(xs, carries=()):
+    """Sort a list of agent slabs by a bitonic network, permuting every
+    list in ``carries`` along.
+
+    The agent axis is the Python list -- the kernel's leading, untiled
+    axis -- so a compare-exchange is an elementwise min/max pair on
+    whole slabs, or a compare and selects when planes are carried; no
+    reshape.  The list is topped up to a power of two with ``None``, a
+    +inf sentinel of weight 0, and every compare-exchange that meets a
+    sentinel is resolved here, at trace time.  Returns the sorted list
+    (sentinels last) and the carried lists.  Ties keep an arbitrary but
+    consistent order: tied values are interchangeable, so the median
+    ranks and the cumulative-weight crossing do not depend on it.
     """
+    p = next_pow2(len(xs))
+    xs = list(xs) + [None] * (p - len(xs))
+    carries = [list(cs) + [None] * (p - len(cs)) for cs in carries]
+    size = 2
+    while size <= p:
+        j = size // 2
+        while j >= 1:
+            for i in range(p):
+                partner = i ^ j
+                if partner < i:
+                    continue
+                # blocks of `size` rows alternate ascending / descending
+                lo, hi = (partner, i) if i & size else (i, partner)
+                a, b = xs[lo], xs[hi]
+                if b is None:
+                    continue
+                if a is None:
+                    xs[lo], xs[hi] = b, a
+                    for cs in carries:
+                        cs[lo], cs[hi] = cs[hi], cs[lo]
+                elif not carries:
+                    xs[lo], xs[hi] = jnp.minimum(a, b), jnp.maximum(a, b)
+                else:
+                    swap = a > b
+                    xs[lo], xs[hi] = (jnp.where(swap, b, a),
+                                      jnp.where(swap, a, b))
+                    for cs in carries:
+                        cs[lo], cs[hi] = (jnp.where(swap, cs[hi], cs[lo]),
+                                          jnp.where(swap, cs[lo], cs[hi]))
+            j //= 2
+        size *= 2
+    return xs, tuple(carries)
+
+
+def _weighted_median_slabs(xs, ws):
+    """The first ascending-sorted value whose cumulative weight reaches
+    1/2 (0 where none does); the sentinels at the end are never chosen.
+    The running sum adds the rows in order, as the oracle's cumsum."""
+    out = cw = 0.0
+    for x, w in zip(xs, ws):
+        if x is None:
+            break
+        prev, cw = cw, cw + w
+        out = jnp.where((cw >= 0.5) & (prev < 0.5), x, out)
+    return out
+
+
+def _mm_estimate_slabs(xs, a, *, num_iters: int, c: float,
+                       unroll: bool = False):
+    """MM estimate of one chunk from its K agent slabs (f32).
+
+    ``a`` holds the K combination weights of one neighborhood, scalars
+    (normalized), or None for uniform weights, which cancel from the
+    IRLS ratio and are not multiplied in.  ``unroll`` unrolls the IRLS
+    sweeps, which lets the scheduler overlap them where K alone gives
+    it too little independent work.
+    """
+    k = len(xs)
+    if a is None:
+        srt, _ = _sort_slabs(xs)
+        med = 0.5 * (srt[(k - 1) // 2] + srt[k // 2])
+        floor = k * _SCALE_FLOOR       # the oracle's den carries a 1/K
+    else:
+        planes = [jnp.full(xs[0].shape, w, jnp.float32) for w in a]
+        srt, (ws,) = _sort_slabs(xs, (planes,))
+        med = _weighted_median_slabs(srt, ws)
+        floor = _SCALE_FLOOR
+    # MAD is the plain median of |x - med| (matches the oracle)
+    ds, _ = _sort_slabs([jnp.abs(x - med) for x in xs])
+    scale = jnp.maximum(_MAD_CONSISTENCY * (0.5 * (ds[(k - 1) // 2]
+                                                  + ds[k // 2])),
+                        _SCALE_FLOOR)
+    inv = 1.0 / (c * scale)            # one divide per coordinate
+
+    def body(_, mu):
+        num = den = None
+        for i, x in enumerate(xs):
+            y = (x - mu) * inv
+            u = jnp.maximum(1.0 - y * y, 0.0)
+            w = u * u if a is None else a[i] * (u * u)    # a_k * b_k
+            num = w * x if num is None else num + w * x
+            den = w if den is None else den + w
+        safe = den > floor
+        return jnp.where(safe, num / jnp.where(safe, den, 1.0), mu)
+
+    return jax.lax.fori_loop(0, num_iters, body, med, unroll=unroll)
+
+
+def _mm_kernel(x_ref, a_ref, o_ref, *residency, k: int, num_iters: int,
+               c: float, weighted: bool, chunk: Tuple[int, int],
+               unroll: bool):
+    """Grid (tiles, K_pad/bk) over (bk, bR, bC) blocks of the (K, R, C)
+    view.  When K streams in several blocks they accumulate in the
+    (K_pad, bR, bC) VMEM scratch; on the last K step every (sr, cw)
+    chunk of the tile is read as K agent slabs and ALL N estimates are
+    computed from them (the N axis is a kernel-body loop, not a grid
+    axis).  ``a_ref`` holds the (K, N) weights flattened, in SMEM."""
     ki = pl.program_id(1)
-    nk = pl.num_programs(1)
-    xs_ref[pl.ds(ki * block_k, block_k), :] = x_ref[...].astype(jnp.float32)
+    xs_ref = x_ref
+    if residency:
+        (xs_ref,) = residency
+        bk = x_ref.shape[0]
+        xs_ref[pl.ds(ki * bk, bk)] = x_ref[...].astype(jnp.float32)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _compute():
-        xp = xs_ref[...]                             # (K_pad, bm), pads=+inf
-        k_pad, bm = xp.shape
-        n_out = a_ref.shape[1]
-        p = next_pow2(k_pad)
-        if p != k_pad:    # top up to the network size, in registers only
-            xp = jnp.concatenate(
-                [xp, jnp.full((p - k_pad, bm), jnp.inf, jnp.float32)], axis=0)
-        valid = (jax.lax.broadcasted_iota(jnp.int32, xp.shape, 0) < k)
-        x = jnp.where(valid, xp, 0.0)                # masked values for IRLS
-        # normalized combination weight columns; sentinel rows are 0
-        a = a_ref[...].astype(jnp.float32)           # (K_pad, N)
-        if p != k_pad:
-            a = jnp.concatenate(
-                [a, jnp.zeros((p - k_pad, n_out), jnp.float32)], axis=0)
+        n_out, br, bc = o_ref.shape
+        sr, cw = chunk
 
-        # --- robust init: (weighted) median + MAD, one shared sort ---
-        if weighted:
-            # carry every weight plane through the single value sort
-            xs, (ws,) = _bitonic_sort_rows(xp, (_weight_planes(a, bm),))
-            med = _weighted_median_planes(xs, ws)    # (N, bm)
+        def rows_chunk(rows):
+            for c0 in range(0, bc, cw):
+                cols = pl.ds(c0, cw)
+                xs = [xs_ref[i, rows, cols].astype(jnp.float32)
+                      for i in range(k)]
+
+                def column(n):
+                    a = ([a_ref[i * n_out + n] for i in range(k)]
+                         if weighted else None)
+                    mu = _mm_estimate_slabs(xs, a, num_iters=num_iters, c=c,
+                                            unroll=unroll)
+                    o_ref[n, rows, cols] = mu.astype(o_ref.dtype)
+
+                if n_out == 1:
+                    column(0)
+                else:
+                    def each(n, carry):
+                        column(n)
+                        return carry
+                    jax.lax.fori_loop(0, n_out, each, 0)
+
+        if br == sr:
+            rows_chunk(pl.ds(0, sr))
         else:
-            xs, _ = _bitonic_sort_rows(xp)
-            med = _median_rows(xs, k)[None]          # (1, bm)
-        # MAD is the plain median of |x - med_n| (matches the oracle);
-        # the deviations differ per neighborhood, so sort all N planes
-        # at once -- still a single network, trailing dims (N, bm).
-        dev = jnp.where(valid[:, None, :],
-                        jnp.abs(x[:, None, :] - med[None]), jnp.inf)
-        ds, _ = _bitonic_sort_rows(dev)
-        scale = jnp.maximum(_MAD_CONSISTENCY * _median_rows(ds, k),
-                            _SCALE_FLOOR)            # (N, bm)
-
-        # --- efficient refinement: fixed-T weighted Tukey IRLS, all N ---
-        c2 = jnp.float32(c * c)
-        xb = x[:, None, :]                           # (P, 1, bm)
-        aw = a[:, :, None]                           # (P, N, 1), 0 on pads
-
-        def body(t, mu):
-            y = (xb - mu[None]) / scale[None]
-            u = jnp.clip(1.0 - (y * y) / c2, 0.0, 1.0)
-            w = aw * (u * u)                         # a_k * b_k
-            num = jnp.sum(w * xb, axis=0)
-            den = jnp.sum(w, axis=0)
-            safe = den > _SCALE_FLOOR
-            return jnp.where(safe, num / jnp.where(safe, den, 1.0), mu)
-
-        mu = jax.lax.fori_loop(0, num_iters, body, med)
-        o_ref[...] = mu.astype(o_ref.dtype)
+            def body(r, carry):
+                rows_chunk(pl.ds(pl.multiple_of(r * sr, sr), sr))
+                return carry
+            jax.lax.fori_loop(0, br // sr, body, 0)
 
 
 def _mm_two_pass_kernel(x_ref, a_ref, o_ref, xs_ref, med_ref, mad_ref, *,
@@ -344,8 +471,9 @@ def _mm_two_pass_kernel(x_ref, a_ref, o_ref, xs_ref, med_ref, mad_ref, *,
                         c: float, weighted: bool):
     """K-major two-pass kernel (see module docstring).
 
-    Same (M/bm, K_pad/bk) grid and one-residency streaming as the
-    single-pass kernel, but the sort networks are bk-sized: pass 1
+    Grid (M/bm, K_pad/bk) over the flattened (K, M) operand, with the
+    single-pass kernel's one-residency streaming, but the sort networks
+    are bk-sized: pass 1
     computes per-K-block robust statistics into the (K/bk, N, bm) VMEM
     scratch intermediates as each block streams in; pass 2 (last K
     step) combines them into a median-of-medians init + pooled MAD
@@ -446,9 +574,13 @@ class LaunchPlan(NamedTuple):
     Computed by ``launch_plan`` -- the same code path ``_launch`` uses
     to configure the pallas_call -- so benchmarks and tests audit the
     kernel that actually runs, not a parallel model.
-    ``input_block_fetches`` counts (bk, bm) update-matrix blocks DMA'd
-    from HBM; it is independent of ``n_out`` by construction (the N axis
-    is not a grid axis).
+    ``input_block_fetches`` counts update-matrix blocks DMA'd from HBM
+    -- (bk, bR, bC) blocks of the (K, R, C) view on the single-pass
+    path, (bk, bm) blocks of the flattened (K, M) operand on the
+    two-pass path, which reads it as one row (R = 1, C = M); it is
+    independent of ``n_out`` by construction (the N axis is not a grid
+    axis).  ``block_m`` is the resolved knob, about the coordinates per
+    grid step; ``block_rows * block_cols`` is the tile that realizes it.
     """
     grid: Tuple[int, int]
     block_m: int
@@ -466,6 +598,12 @@ class LaunchPlan(NamedTuple):
     num_k_blocks: int = 1
     stats_bytes: int = 0      # VMEM-resident per-block stat intermediate
     vmem_bytes: int = 0       # modeled peak VMEM working set
+    # the (R, C) view of one agent's coordinates and the tile of a step
+    view: str = "flat"        # "rows": the leaf's own matrix; "flat"
+    rows: int = 1
+    cols: int = 0
+    block_rows: int = 1
+    block_cols: int = 0
 
     @property
     def total_bytes(self) -> int:
@@ -474,17 +612,42 @@ class LaunchPlan(NamedTuple):
         in VMEM scratch, never HBM)."""
         return self.input_bytes + self.weight_bytes + self.output_bytes
 
+    def tile(self, t: int) -> Tuple[int, ...]:
+        """Block index of grid tile ``t`` in the view, after the agent
+        axis: (row block, column block), or (column block,) when the
+        view is one row (the two-pass (K, M) operand)."""
+        if self.path == "two_pass":
+            return (t,)
+        return divmod(t, -(-self.cols // self.block_cols))
 
-def single_pass_vmem_bytes(k: int, n: int, block_m: int) -> int:
-    """Modeled peak VMEM working set of the single-pass kernel: the
-    (K_pad, bm) residency, ~3 (P, bm) f32 sort/mask buffers, and ~5
-    (P, N, bm) f32 planes (broadcast weight carries + their sort
-    ping-pong, deviations + sorted copy, IRLS y/u/w peak) -- the
-    full-K networks carry every weight plane, which is exactly what
-    the two-pass path bounds away."""
-    k_pad = k + (k % 2)
-    p = next_pow2(max(k_pad, 2))
-    return 4 * (k_pad * block_m + 3 * p * block_m + 5 * p * n * block_m)
+    @property
+    def residency_bytes(self) -> int:
+        """The f32 (K_pad, tile) VMEM scratch the K blocks accumulate in
+        -- always on the two-pass path, only when K streams in several
+        blocks on the single-pass path (one block is its own residency)."""
+        if self.path == "single" and self.num_k_blocks == 1:
+            return 0
+        return 4 * self.k_pad * self.block_rows * self.block_cols
+
+
+def single_pass_vmem_bytes(k: int, n: int, block_m: int, *,
+                           block_k: Optional[int] = None,
+                           itemsize: int = 4) -> int:
+    """Modeled peak VMEM working set of the single-pass kernel at
+    ``block_m`` coordinates per grid step: the double-buffered (bk,
+    tile) input and (N, tile) output blocks, the f32 (K_pad, tile)
+    residency when K streams in several blocks, and one compute chunk
+    (at most ``_CHUNK_COORDS`` coordinates): the K agent slabs plus ~8
+    next_pow2(K)-row planes (weight carries and their sort ping-pong,
+    deviations and their sorted copy, IRLS temporaries).  The N weight
+    columns are estimated one after another, so N enters only through
+    the output blocks."""
+    bk = k + (k % 2) if block_k is None else int(block_k)
+    k_pad = -(-k // bk) * bk
+    p = next_pow2(max(k, 2))
+    residency = 4 * k_pad * block_m if k_pad > bk else 0
+    return (2 * (bk + n) * block_m * itemsize + residency
+            + 4 * (k_pad + 8 * p) * min(block_m, _CHUNK_COORDS))
 
 
 def two_pass_vmem_bytes(k: int, n: int, block_m: int, block_k: int,
@@ -540,12 +703,15 @@ def launch_plan(k: int, m: int, n: int = 1, *,
                 block_m: Optional[int] = None,
                 block_k: Optional[int] = None,
                 path: Optional[str] = None,
-                n_chunk: Optional[int] = None) -> LaunchPlan:
+                n_chunk: Optional[int] = None,
+                shape: Optional[Tuple[int, ...]] = None) -> LaunchPlan:
     """Resolve the kernel path + tile sizes (via kernels.tuning when
     unset) and derive the grid, modeled HBM traffic and modeled VMEM
-    residency for a (K, M) x (K, N) run.  ``path=None`` auto-selects:
-    the cached tuning winner for the workload if one names a path, else
-    the ``auto_path`` VMEM-crossover heuristic."""
+    residency for a (K, M) x (K, N) run.  ``shape`` is one agent's
+    coordinate shape (M of them; default ``(m,)``): it picks the
+    single-pass view (``leaf_view``).  ``path=None`` auto-selects: the
+    cached tuning winner for the workload if one names a path, else the
+    ``auto_path`` VMEM-crossover heuristic."""
     if path is not None and path not in PATHS:
         raise ValueError(f"unknown kernel path {path!r}; known: {PATHS}")
     if block_m is None or block_k is None or path is None:
@@ -564,9 +730,9 @@ def launch_plan(k: int, m: int, n: int = 1, *,
         path = auto_path(k, n, block_m)
 
     itemsize = jnp.dtype(dtype).itemsize
-    m_total = m + ((-m) % block_m)
 
     if path == "two_pass":
+        m_total = m + ((-m) % block_m)
         bk = two_pass_block_k(k) if block_k is None else int(block_k)
         if bk < 2 or bk & (bk - 1):
             raise ValueError(
@@ -587,6 +753,7 @@ def launch_plan(k: int, m: int, n: int = 1, *,
             path=path, n_chunk=nc, num_k_blocks=kb,
             stats_bytes=2 * kb * n * block_m * 4,
             vmem_bytes=two_pass_vmem_bytes(k, n, block_m, bk, nc),
+            rows=1, cols=m_total, block_rows=1, block_cols=block_m,
         )
 
     if block_k is None:
@@ -596,19 +763,31 @@ def launch_plan(k: int, m: int, n: int = 1, *,
             raise ValueError(f"block_k must be positive and even, got {block_k}")
         bk = block_k
     k_pad = ((k + bk - 1) // bk) * bk
-    grid = (m_total // block_m, k_pad // bk)
+    view, rows, cols = leaf_view((m,) if shape is None else shape)
+    br, bc = single_pass_tile(rows, cols, block_m, dtype)
+    grid = (-(-rows // br) * -(-cols // bc), k_pad // bk)
     fetches = grid[0] * grid[1]
     return LaunchPlan(
         grid=grid, block_m=block_m, block_k=bk, k_pad=k_pad,
-        m_total=m_total, n_out=n,
+        m_total=rows * cols, n_out=n,
         input_block_fetches=fetches,
-        input_bytes=fetches * bk * block_m * itemsize,
-        weight_bytes=k_pad * n * 4,
-        output_bytes=n * m_total * itemsize,
+        input_bytes=fetches * bk * br * bc * itemsize,
+        weight_bytes=k * n * 4,
+        output_bytes=n * rows * cols * itemsize,
         path=path, n_chunk=1, num_k_blocks=k_pad // bk,
         stats_bytes=0,
-        vmem_bytes=single_pass_vmem_bytes(k, n, block_m),
+        vmem_bytes=single_pass_vmem_bytes(k, n, br * bc, block_k=bk,
+                                          itemsize=itemsize),
+        view=view, rows=rows, cols=cols, block_rows=br, block_cols=bc,
     )
+
+
+def plan_of_record(r: dict) -> LaunchPlan:
+    """The launch plan of one ``ops.record_workloads()`` entry."""
+    shape = (r["rows"], r["cols"]) if r.get("view") == "rows" else None
+    return launch_plan(r["k"], r["m"], r["n"], dtype=r["dtype"],
+                       block_m=r["block_m"], block_k=r["block_k"],
+                       path=r.get("path"), shape=shape)
 
 
 class KernelCall(NamedTuple):
@@ -660,20 +839,43 @@ def kernel_call(plan: LaunchPlan, *, k: int, dtype=jnp.float32,
             pltpu.VMEM((plan.num_k_blocks, n_out, plan.block_m),
                        jnp.float32),
         )
-    else:
-        kernel = functools.partial(_mm_kernel, k=k, block_k=bk,
-                                   num_iters=num_iters, c=c,
-                                   weighted=weighted)
-        scratch = (pltpu.VMEM((k_pad, plan.block_m), jnp.float32),)
+        return KernelCall(
+            kernel=kernel,
+            grid=plan.grid,
+            in_specs=(
+                pl.BlockSpec((bk, plan.block_m), lambda mi, ki: (ki, mi)),
+                pl.BlockSpec((k_pad, n_out), lambda mi, ki: (0, 0)),
+            ),
+            out_specs=pl.BlockSpec((n_out, plan.block_m),
+                                   lambda mi, ki: (0, mi)),
+            out_shape=jax.ShapeDtypeStruct((n_out, plan.m_total), dtype),
+            scratch_shapes=scratch,
+        )
+
+    br, bc = plan.block_rows, plan.block_cols
+    # one compute chunk: a sublane tile of rows.  Up to 8 agents the
+    # slabs stay in registers at two vregs each; up to 16 the IRLS
+    # sweeps are unrolled -- both give the scheduler independent work
+    # (on one v5e chip the unrolled sweeps ran 1.45x, 1.21x and 1.44x
+    # as fast at K=4, 8 and 16, two-vreg slabs 1.9x and 1.06x at K=4, 8)
+    sr = min(sublane_rows(dtype), br)
+    cw = 2 * LANE if (k <= 8 and bc % (2 * LANE) == 0
+                      and sr * 2 * LANE <= _CHUNK_COORDS) else LANE
+    kernel = functools.partial(_mm_kernel, k=k, num_iters=num_iters, c=c,
+                               weighted=weighted, chunk=(sr, cw),
+                               unroll=k <= 16)
+    scratch = ((pltpu.VMEM((k_pad, br, bc), jnp.float32),)
+               if plan.num_k_blocks > 1 else ())
+    tile = plan.tile
     return KernelCall(
         kernel=kernel,
         grid=plan.grid,
         in_specs=(
-            pl.BlockSpec((bk, plan.block_m), lambda mi, ki: (ki, mi)),
-            pl.BlockSpec((k_pad, n_out), lambda mi, ki: (0, 0)),
+            pl.BlockSpec((bk, br, bc), lambda t, ki: (ki,) + tile(t)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
-        out_specs=pl.BlockSpec((n_out, plan.block_m), lambda mi, ki: (0, mi)),
-        out_shape=jax.ShapeDtypeStruct((n_out, plan.m_total), dtype),
+        out_specs=pl.BlockSpec((n_out, br, bc), lambda t, ki: (0,) + tile(t)),
+        out_shape=jax.ShapeDtypeStruct((n_out, plan.rows, plan.cols), dtype),
         scratch_shapes=scratch,
     )
 
@@ -681,7 +883,7 @@ def kernel_call(plan: LaunchPlan, *, k: int, dtype=jnp.float32,
 def _pad_inputs(
     x: jnp.ndarray, a: jnp.ndarray, *, plan: LaunchPlan
 ) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
-    """Pad (K, M) values and (K, N) weights to the plan's grid geometry.
+    """Pad (K, M) values and (K, N) weights to the two-pass plan's grid.
 
     K is padded to a multiple of the (even) K block with +inf sentinel
     rows (weight 0); the kernel tops the sort operand up to the next
@@ -708,9 +910,30 @@ def _pad_inputs(
     return xp, ap, bk
 
 
+def _view_inputs(x: jnp.ndarray, plan: LaunchPlan) -> jnp.ndarray:
+    """(K, ...) values -> the single-pass plan's (K_pad, R, C) view.
+
+    The "rows" view is a reshape of the leaf (a bitcast on the TPU).
+    The "flat" view pads M with ZERO columns to R * 128: a non-finite
+    pad would flow through the in-kernel MAD as inf - inf = nan; zero
+    columns are inert (median 0, scale floored, IRLS exact).  K pad rows
+    (a K block that does not divide K) are zeros the kernel never reads.
+    """
+    k = x.shape[0]
+    if plan.view == "rows":
+        xv = x.reshape(k, plan.rows, plan.cols)
+    else:
+        flat = x.reshape(k, -1)
+        xv = jnp.pad(flat, ((0, 0), (0, plan.m_total - flat.shape[1])))
+        xv = xv.reshape(k, plan.rows, plan.cols)
+    if plan.k_pad != k:
+        xv = jnp.pad(xv, ((0, plan.k_pad - k), (0, 0), (0, 0)))
+    return xv
+
+
 def _launch(
     x: jnp.ndarray,
-    a: jnp.ndarray,                  # (K, N) normalized weight columns
+    a: jnp.ndarray,                  # (K, N) weight columns
     *,
     weighted: bool,
     num_iters: int,
@@ -721,7 +944,7 @@ def _launch(
     path: Optional[str] = None,
     n_chunk: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Run the fused kernel: (K, M) values x (K, N) weights -> (N, M).
+    """Run the fused kernel: (K, ...) values x (K, N) weights -> (N, ...).
 
     Weight columns are normalized (and invalid columns replaced by
     uniform) here -- the in-kernel weighted median selects the absolute
@@ -731,14 +954,18 @@ def _launch(
     """
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    k, m = x.shape
+    k, shape = x.shape[0], tuple(x.shape[1:])
+    m = math.prod(shape)
     if weighted:
         a = location.normalize_weights(a, dtype=jnp.float32)
     n_out = a.shape[1]
     plan = launch_plan(k, m, n_out, dtype=x.dtype,
                        block_m=block_m, block_k=block_k,
-                       path=path, n_chunk=n_chunk)
-    xp, ap, _ = _pad_inputs(x, a, plan=plan)
+                       path=path, n_chunk=n_chunk, shape=shape)
+    if plan.path == "two_pass":
+        xp, ap, _ = _pad_inputs(x.reshape(k, m), a, plan=plan)
+    else:
+        xp, ap = _view_inputs(x, plan), a.astype(jnp.float32).reshape(-1)
     call = kernel_call(plan, k=k, dtype=x.dtype, num_iters=num_iters, c=c,
                        weighted=weighted)
     out = pl.pallas_call(
@@ -751,11 +978,50 @@ def _launch(
         interpret=interpret,
         name=KERNEL_NAMES[plan.path],
     )(xp, ap)
-    return out[:, :m]
+    if plan.view == "rows":
+        return out.reshape((n_out,) + shape)
+    return out.reshape(n_out, -1)[:, :m].reshape((n_out,) + shape)
 
 
 def _uniform_weights(k: int) -> jnp.ndarray:
     return jnp.full((k, 1), 1.0 / k, dtype=jnp.float32)
+
+
+def mm_aggregate_nd(
+    x: jnp.ndarray,
+    a: Optional[jnp.ndarray] = None,
+    *,
+    num_iters: int = 10,
+    c: float = mestimators.TUKEY_C95,
+    block_m: Optional[int] = None,
+    block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+    path: Optional[str] = None,
+    n_chunk: Optional[int] = None,
+) -> jnp.ndarray:
+    """MM-aggregate a (K, ...) array along axis 0 via Pallas, one launch.
+
+    ``a`` is None (uniform), a (K,) vector of combination weights
+    (-> (...)), or (K, N) weight columns, one neighborhood each (a_{.n}
+    of Eq. 15; -> (N, ...)); weights are normalized internally (invalid
+    columns fall back to uniform, as in
+    ``repro.core.location.normalize_weights``).  The trailing shape
+    picks the single-pass view (``leaf_view``), so a leaf is read in its
+    own layout where it can be.  Block sizes and the kernel path default
+    to the kernels.tuning cache/heuristic.
+    """
+    k = x.shape[0]
+    batched = a is not None and a.ndim == 2
+    if a is None:
+        aw = _uniform_weights(k)
+    elif a.shape == (k,) or (batched and a.shape[0] == k):
+        aw = a.reshape(k, -1)
+    else:
+        raise ValueError(f"weights must be ({k},) or ({k}, N), got {a.shape}")
+    out = _launch(x, aw, weighted=a is not None, num_iters=num_iters, c=c,
+                  block_m=block_m, block_k=block_k, interpret=interpret,
+                  path=path, n_chunk=n_chunk)
+    return out if batched else out[0]
 
 
 def mm_aggregate_2d(
@@ -769,26 +1035,14 @@ def mm_aggregate_2d(
     interpret: Optional[bool] = None,
     path: Optional[str] = None,
 ) -> jnp.ndarray:
-    """MM-aggregate a (K, M) array along axis 0 -> (M,) via Pallas.
-
-    ``a`` is an optional (K,) vector of combination weights; it is
-    normalized internally (invalid weights fall back to uniform, as in
-    ``repro.core.location.normalize_weights``).  Block sizes and the
-    kernel path default to the kernels.tuning cache/heuristic.
-    """
+    """MM-aggregate a (K, M) array along axis 0 -> (M,) via Pallas, with
+    optional (K,) combination weights (see ``mm_aggregate_nd``)."""
     if x.ndim != 2:
         raise ValueError(f"mm_aggregate_2d wants (K, M), got {x.shape}")
-    k = x.shape[0]
-    if a is None:
-        aw, weighted = _uniform_weights(k), False
-    else:
-        if a.shape != (k,):
-            raise ValueError(f"weights must be ({k},), got {a.shape}")
-        aw, weighted = a.reshape(k, 1), True
-    out = _launch(x, aw, weighted=weighted, num_iters=num_iters, c=c,
-                  block_m=block_m, block_k=block_k, interpret=interpret,
-                  path=path)
-    return out[0]
+    if a is not None and a.shape != (x.shape[0],):
+        raise ValueError(f"weights must be ({x.shape[0]},), got {a.shape}")
+    return mm_aggregate_nd(x, a, num_iters=num_iters, c=c, block_m=block_m,
+                           block_k=block_k, interpret=interpret, path=path)
 
 
 def mm_aggregate_batched_2d(
@@ -806,17 +1060,15 @@ def mm_aggregate_batched_2d(
     """Batched weighted MM-aggregation: (K, M) values, (K, N) weight
     columns -> (N, M) estimates, one kernel launch.
 
-    Column n of ``a`` is one neighborhood's combination weights (a_{.n}
-    of Eq. 15), normalized internally per column.  The x tile is
-    streamed from HBM exactly ONCE regardless of N -- all N estimates
-    are computed in the kernel body from the single VMEM residency (see
-    the module docstring); this is the diffusion hot path (K, N = graph
-    size).  ``path`` selects the single-pass or two-pass (K >> 64)
-    kernel; None auto-selects via launch_plan.
+    The x tile is streamed from HBM exactly ONCE regardless of N -- all
+    N estimates are computed in the kernel body from the single VMEM
+    residency (see the module docstring); this is the diffusion hot
+    path (K, N = graph size).  ``path`` selects the single-pass or
+    two-pass (K >> 64) kernel; None auto-selects via launch_plan.
     """
     if x.ndim != 2 or a.ndim != 2 or a.shape[0] != x.shape[0]:
         raise ValueError(
             f"want x (K, M) and a (K, N), got {x.shape} and {a.shape}")
-    return _launch(x, a, weighted=True, num_iters=num_iters, c=c,
-                   block_m=block_m, block_k=block_k, interpret=interpret,
-                   path=path, n_chunk=n_chunk)
+    return mm_aggregate_nd(x, a, num_iters=num_iters, c=c, block_m=block_m,
+                           block_k=block_k, interpret=interpret, path=path,
+                           n_chunk=n_chunk)

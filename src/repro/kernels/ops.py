@@ -72,8 +72,12 @@ _ACTIVE_RECORDERS: list = []
 
 @contextlib.contextmanager
 def record_workloads():
-    """Collect {k, m, n, dtype, backend, block_m, block_k} dicts for
-    every distinct engine workload resolved inside the scope."""
+    """Collect {k, m, n, dtype, backend, block_m, block_k, path, view,
+    rows, cols} dicts for every distinct engine workload resolved
+    inside the scope: ``view`` is the single-pass kernel's (R, C) view
+    of one agent's coordinates (``mm_aggregate.leaf_view``), "rows" for
+    the leaf's own matrix, "flat" for the flattened fallback (None on
+    the jnp backend, which runs no kernel)."""
     records: list = []
     _ACTIVE_RECORDERS.append(records)
     try:
@@ -108,8 +112,8 @@ def _agg_nd_impl(x, a, *, num_iters, c, block_m, block_k, interpret, backend,
 
     The jnp backend never flattens trailing dims (the estimate is
     elementwise), so auto-axis sharding of multi-dim gradient leaves
-    survives under GSPMD; the pallas path is VMEM-tiled and reshapes to
-    (K, M) by construction.
+    survives under GSPMD; the pallas kernel reads the leaf through its
+    (K, R, C) view, which is the leaf's own layout where it can be.
     """
     if backend == "jnp":
         af = None if a is None else a.astype(jnp.float32)
@@ -117,11 +121,9 @@ def _agg_nd_impl(x, a, *, num_iters, c, block_m, block_k, interpret, backend,
             x.astype(jnp.float32), a=af, loss=_tukey(c),
             num_iters=num_iters).estimate
         return out.astype(x.dtype)
-    k = x.shape[0]
-    out = _k.mm_aggregate_2d(x.reshape(k, -1), a, num_iters=num_iters, c=c,
-                             block_m=block_m, block_k=block_k,
-                             interpret=interpret, path=path)
-    return out.reshape(x.shape[1:])
+    return _k.mm_aggregate_nd(x, a, num_iters=num_iters, c=c,
+                              block_m=block_m, block_k=block_k,
+                              interpret=interpret, path=path)
 
 
 _agg_nd = jax.jit(_agg_nd_impl, static_argnames=_AGG_STATICS)
@@ -132,22 +134,21 @@ _agg_nd_donated = jax.jit(_agg_nd_impl, static_argnames=_AGG_STATICS,
                           donate_argnums=(0,))
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "num_iters", "c", "block_m", "block_k", "interpret", "backend", "path"))
-def _agg_batched_2d(flat, a, *, num_iters, c, block_m, block_k, interpret,
-                    backend, path=None):
-    """(K, M) x (K, N) -> (N, M)."""
+@functools.partial(jax.jit, static_argnames=_AGG_STATICS)
+def _agg_batched(x, a, *, num_iters, c, block_m, block_k, interpret,
+                 backend, path=None):
+    """(K, ...) x (K, N) -> (N, ...)."""
     if backend == "jnp":
-        xf = flat.astype(jnp.float32)
+        xf = x.astype(jnp.float32)
         out = jax.vmap(
             lambda col: location.mm_estimate(
                 xf, a=col.astype(jnp.float32), loss=_tukey(c),
                 num_iters=num_iters).estimate,
             in_axes=1)(a)
-        return out.astype(flat.dtype)
-    return _k.mm_aggregate_batched_2d(flat, a, num_iters=num_iters, c=c,
-                                      block_m=block_m, block_k=block_k,
-                                      interpret=interpret, path=path)
+        return out.astype(x.dtype)
+    return _k.mm_aggregate_nd(x, a, num_iters=num_iters, c=c,
+                              block_m=block_m, block_k=block_k,
+                              interpret=interpret, path=path)
 
 
 def _agg_tree_impl(leaves, a, *, sizes, offsets, shapes, dtypes, opts):
@@ -264,12 +265,15 @@ class AggregationEngine:
             return tuning.get_blocks(k, m, n, dtype)[0], self.block_k
         return tuning.get_blocks(k, m, n, dtype)
 
-    def _opts(self, x, k: int, m: int, n: int = 1):
+    def _opts(self, x, k: int, m: int, n: int = 1, shape=None):
+        """Resolve (and record) one launch's static options; ``shape``
+        is the per-agent shape the kernel sees (default x.shape[1:])."""
         entry = {"k": int(k), "m": int(m), "n": int(n),
                  "dtype": jnp.dtype(x.dtype).name, "backend": self.backend}
         if self.backend != "pallas":
             bm, bk = self._blocks_for(x, k, m, n)
-            entry.update(block_m=bm, block_k=bk, path=None)
+            entry.update(block_m=bm, block_k=bk, path=None, view=None,
+                         rows=None, cols=None)
             _record_workload(entry)
             return dict(num_iters=self.num_iters, c=self.c, block_m=bm,
                         block_k=bk, interpret=self.interpret,
@@ -283,10 +287,13 @@ class AggregationEngine:
         # AND the single<->two-pass path (tuning winner or the VMEM
         # crossover heuristic); recording the *resolved* geometry makes
         # the launch audits ground truth for both paths.
-        plan = _k.launch_plan(k, m, n, dtype=x.dtype, block_m=self.block_m,
-                              block_k=self.block_k, path=self.path)
+        plan = _k.launch_plan(
+            k, m, n, dtype=x.dtype, block_m=self.block_m,
+            block_k=self.block_k, path=self.path,
+            shape=tuple(x.shape[1:]) if shape is None else shape)
         entry.update(block_m=plan.block_m, block_k=plan.block_k,
-                     path=plan.path)
+                     path=plan.path, view=plan.view, rows=plan.rows,
+                     cols=plan.cols)
         _record_workload(entry)
         return dict(num_iters=self.num_iters, c=self.c,
                     block_m=plan.block_m, block_k=plan.block_k,
@@ -308,9 +315,7 @@ class AggregationEngine:
         input tile streamed from HBM exactly once regardless of N."""
         k = x.shape[0]
         m = int(x.size) // max(k, 1)
-        out = _agg_batched_2d(x.reshape(k, -1), a,
-                              **self._opts(x, k, m, a.shape[1]))
-        return out.reshape((a.shape[1],) + x.shape[1:])
+        return _agg_batched(x, a, **self._opts(x, k, m, a.shape[1]))
 
     # -- pytrees -----------------------------------------------------------
 
@@ -327,7 +332,8 @@ class AggregationEngine:
         layout = self._layout_for(leaves, treedef)
         m_total = sum(layout.sizes)
         opts = tuple(sorted(
-            self._opts(leaves[0], layout.k, m_total).items()))
+            self._opts(leaves[0], layout.k, m_total,
+                       shape=(m_total,)).items()))
         fn = _agg_tree_flat_donated if self.donate_leaves else _agg_tree_flat
         kwargs = dict(sizes=layout.sizes, offsets=layout.offsets,
                       shapes=layout.shapes, dtypes=layout.dtypes, opts=opts)

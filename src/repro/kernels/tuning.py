@@ -1,7 +1,8 @@
 """Block-size + kernel-path autotuner for the MM-aggregation kernels.
 
-The kernel's performance knobs are ``block_m`` (the lane tile, how many
-coordinates share one VMEM residency), ``block_k`` (the K stream block;
+The kernel's performance knobs are ``block_m`` (how many coordinates
+share one VMEM residency: about the coordinates of one single-pass grid
+step, the lane tile of a two-pass step), ``block_k`` (the K stream block;
 ``None`` streams the whole padded K axis as one block on the
 single-pass path, or resolves to ``mm_aggregate.two_pass_block_k`` on
 the two-pass path) and -- since the K-major two-pass kernel landed --
@@ -10,12 +11,11 @@ depends on the workload tuple
 
     (K, M, N, dtype)
 
-because the kernel-body batch over N weight columns multiplies the
-in-register working set: on the single-pass path the weighted-median
-carry planes and the MAD deviation planes are (K_pad2, N, block_m) f32,
-so large K*N wants a narrower block_m (and, past the VMEM budget, the
-two-pass path) while small problems want the widest tile the M axis
-supports (less grid overhead, better DMA efficiency).
+because the double-buffered input and output blocks grow with K and N,
+and the full-K sort networks with K: large K wants a narrower block_m
+(and, past the VMEM budget, the two-pass path) while small problems
+want the widest tile the M axis supports (less grid overhead, better
+DMA efficiency).
 
 Entry points:
 
@@ -56,11 +56,15 @@ import jax.numpy as jnp
 
 from repro.kernels import mm_aggregate as _mm
 
-LANE = 128
+LANE = _mm.LANE
+sublane_rows = _mm.sublane_rows
 # the per-core VMEM budget lives with the kernel geometry model
 # (mm_aggregate.VMEM_BUDGET_BYTES); this alias keeps older imports alive
 _VMEM_BUDGET_BYTES = _mm.VMEM_BUDGET_BYTES
+# widest two-pass lane tile, and most coordinates per single-pass step
+# (128 rows of 1024 lanes: a 2 MiB f32 input block at K=4)
 _MAX_BLOCK_M = 1024
+_MAX_SINGLE_BLOCK_M = 128 * 1024
 
 BlockChoice = Tuple[int, Optional[int]]   # (block_m, block_k)
 
@@ -185,37 +189,40 @@ def heuristic_blocks(k: int, m: int, n: int = 1,
                      dtype=jnp.float32) -> BlockChoice:
     """VMEM-budget fallback used when no autotune measurement is cached.
 
-    The lane tile is sized against the kernel's own working-set models
+    The tile is sized against the kernel's own working-set models
     (``mm_aggregate.single_pass_vmem_bytes`` / ``two_pass_vmem_bytes``
     -- the same models ``launch_plan`` reports and ``repro.analysis``
     audits), so the heuristic can never pick a geometry whose resolved
     path overflows the budget by the model's own account.  Meshes below
-    the two-pass crossover take the widest single-pass tile that fits;
-    larger meshes get whichever path affords the wider tile -- in
-    practice the two-pass kernel, whose working set stays bounded in K
-    (``auto_path`` then resolves the path from the same models).
-    Clamped to [128, 1024] and to the (lane-rounded) problem width so
-    tiny M never over-pads; the K axis streams as one block on the
+    the two-pass crossover take the widest single-pass tile that fits,
+    up to ``_MAX_SINGLE_BLOCK_M`` coordinates; larger meshes get
+    whichever path affords the wider tile -- in practice the two-pass
+    kernel, whose working set stays bounded in K (``auto_path`` then
+    resolves the path from the same models; a two-pass lane tile is at
+    most ``_MAX_BLOCK_M``).  Clamped to the (lane-rounded) problem width
+    so tiny M never over-pads; the K axis streams as one block on the
     single-pass path while the two-pass path derives its own
     power-of-two K block in mm_aggregate.
     """
     k, n = int(k), max(int(n), 1)
     m_lanes = max(LANE, ((int(m) + LANE - 1) // LANE) * LANE)
-    cap = min(_MAX_BLOCK_M, m_lanes)
+    itemsize = jnp.dtype(dtype).itemsize
 
-    def widest(model_bytes):
-        bm = cap
+    def widest(cap, model_bytes):
+        bm = min(cap, m_lanes)
         while bm > 0 and model_bytes(bm) > _VMEM_BUDGET_BYTES:
             bm -= LANE
         return bm
 
-    bm_single = widest(lambda bm: _mm.single_pass_vmem_bytes(k, n, bm))
+    bm_single = widest(_MAX_SINGLE_BLOCK_M,
+                       lambda bm: _mm.single_pass_vmem_bytes(
+                           k, n, bm, itemsize=itemsize))
     if k < _mm._TWO_PASS_MIN_K:
         # small meshes stay single-pass (bit-stable with the
         # pre-two-pass kernel) even when the narrowest tile overflows
         return max(LANE, bm_single), None
     bk = _mm.two_pass_block_k(k)
-    bm_two = widest(lambda bm: _mm.two_pass_vmem_bytes(
+    bm_two = widest(_MAX_BLOCK_M, lambda bm: _mm.two_pass_vmem_bytes(
         k, n, bm, bk, _mm.two_pass_n_chunk(n, bm, bk)))
     return max(LANE, bm_single, bm_two), None
 
@@ -286,21 +293,13 @@ def _time_call_us(fn, *args, reps: int = 3) -> float:
     return (time.perf_counter() - t0) / reps * 1e6
 
 
-def sublane_rows(dtype) -> int:
-    """Row tile of one vreg for ``dtype``: a K block shorter than the
-    padded K axis must be a multiple of it (8 rows for 32-bit, 16 for
-    16-bit) or Mosaic refuses the BlockSpec."""
-    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-
-
 def candidate_blocks(k: int, m: int, n: int = 1,
                      dtype=jnp.float32) -> Sequence[BlockChoice]:
-    """Default single-pass sweep: lane tiles around the heuristic,
-    full-K streaming plus one K-split when half the padded K axis is a
-    whole number of sublane tiles."""
-    bms = sorted({LANE, 256, 512, heuristic_blocks(k, m, n, dtype)[0]})
-    m_lanes = max(LANE, ((int(m) + LANE - 1) // LANE) * LANE)
-    bms = [bm for bm in bms if bm <= m_lanes] or [LANE]
+    """Default single-pass sweep: the heuristic tile, a half and a
+    quarter of it, full-K streaming plus one K-split when half the
+    padded K axis is a whole number of sublane tiles."""
+    bm0 = heuristic_blocks(k, m, n, dtype)[0]
+    bms = sorted({max(LANE, bm0 // d // LANE * LANE) for d in (1, 2, 4)})
     bks: list = [None]
     half = (int(k) + (int(k) % 2)) // 2
     if half % sublane_rows(dtype) == 0:

@@ -295,11 +295,7 @@ def _audit_from_records(records) -> Optional[dict]:
     from repro.kernels import mm_aggregate  # deferred: keep import light
     plans = []
     for r in pallas:
-        plan = mm_aggregate.launch_plan(
-            r["k"], r["m"], r["n"], dtype=r["dtype"],
-            block_m=r["block_m"], block_k=r["block_k"],
-            path=r.get("path"))
-        d = plan._asdict()
+        d = mm_aggregate.plan_of_record(r)._asdict()
         d["grid"] = list(d["grid"])
         plans.append(d)
     if len(plans) == 1:

@@ -169,20 +169,42 @@ def test_mutation_grid_mismatch_short_circuits():
 
 
 def test_vmem_budget_flags_avoidable_overflow_only():
-    # K=16 at an absurd pinned tile: single model blows the budget but a
-    # narrower tile would fit -> avoidable -> flagged
-    plan = mk.launch_plan(16, 2048, 64, block_m=1024, path="single")
+    # K=16, N=64 at an absurd pinned tile: the double-buffered blocks
+    # blow the budget but a narrower tile would fit -> avoidable -> flagged
+    plan = mk.launch_plan(16, 1 << 20, 64, block_m=1 << 16, path="single")
     call = mk.kernel_call(plan, k=16)
     assert any(f.rule == "vmem-budget"
                for f in contracts.audit_call(plan, call))
-    # forced small mesh: K=64 / N=32 overflows even at a 128 tile, and
-    # the two-pass crossover excludes it -> sanctioned, not flagged
-    plan = mk.launch_plan(64, 128, 32, block_m=128, path="single")
+    # forced small mesh: K=64 / N=4096 overflows even at a 128 tile (the
+    # output blocks alone), and the two-pass crossover excludes it ->
+    # sanctioned, not flagged
+    plan = mk.launch_plan(64, 128, 4096, block_m=128, path="single")
     assert mk.single_pass_vmem_bytes(plan.k_pad, plan.n_out, 128) \
         > mk.VMEM_BUDGET_BYTES
     call = mk.kernel_call(plan, k=64)
     assert not any(f.rule == "vmem-budget"
                    for f in contracts.audit_call(plan, call))
+
+
+@pytest.mark.parametrize("block_k", [None, 2])
+def test_single_pass_rows_view_audits_clean_and_refetch_is_caught(block_k):
+    """A leaf read as its own (64, 1024) matrix in (8, 1024) tiles, with
+    K whole or streamed in two blocks (then through the residency),
+    audits clean; an index map that fetches one tile for every grid
+    step is caught."""
+    plan = mk.launch_plan(4, 64 * 1024, 1, block_m=8192, block_k=block_k,
+                          shape=(64, 1024))
+    assert plan.view == "rows" and plan.grid[0] == 8
+    assert (plan.block_rows, plan.block_cols) == (8, 1024)
+    call = mk.kernel_call(plan, k=4)
+    assert bool(call.scratch_shapes) == (block_k is not None)
+    assert contracts.audit_call(plan, call) == []
+    bad_spec = pl.BlockSpec(call.in_specs[0].block_shape,
+                            lambda t, ki: (ki, 0, 0))
+    bad = call._replace(in_specs=(bad_spec, call.in_specs[1]))
+    got = _rules(contracts.audit_call(plan, bad))
+    assert ("one-residency", "refetch") in got
+    assert ("one-residency", "coverage") in got
 
 
 def test_heuristic_blocks_respect_the_vmem_model():

@@ -5,6 +5,8 @@ contract: every (K, M, dtype, weights, contamination) combination must
 match ref.mm_aggregate_ref to float tolerance.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,12 +39,17 @@ def test_dtype_sweep(dtype):
         atol=1e-2 if dtype == jnp.bfloat16 else 1e-5)
 
 
-@pytest.mark.parametrize("block_m", [128, 256, 1024])
+@pytest.mark.parametrize("block_m", [128, 256, 1024, 6144, 16384])
 def test_block_size_invariance(block_m):
-    x = jax.random.normal(jax.random.key(3), (8, 777))
-    got = ops.mm_aggregate(x, interpret=True, block_m=block_m)
-    want = ref.mm_aggregate_ref(x)
-    np.testing.assert_allclose(got, want, atol=1e-5)
+    """The estimate does not depend on the tile: the flat view of an
+    odd M, and a leaf read as its own (40, 384) matrix, whose tile runs
+    from one (8, 128) block to the whole matrix, through 16-row blocks
+    whose last one overhangs the 40 rows."""
+    for shape in ((8, 777), (8, 40, 384)):
+        x = jax.random.normal(jax.random.key(3), shape)
+        got = ops.mm_aggregate(x, interpret=True, block_m=block_m)
+        want = ref.mm_aggregate_ref(x)
+        np.testing.assert_allclose(got, want, atol=1e-5, err_msg=str(shape))
 
 
 @given(seed=st.integers(0, 10_000), k=st.integers(2, 24),
@@ -196,19 +203,93 @@ def test_batched_block_invariance():
                                        err_msg=f"bm={bm} bk={bk}")
 
 
+# ---------------------------------------------------------------------------
+# the single-pass (K, R, C) view: a leaf's own matrix or the flat rows
+# ---------------------------------------------------------------------------
+
+VIEW_CASES = [
+    # (per-agent shape incl. K, expected view, block_m): the train
+    # step's leaf geometries at small widths, then the flat fallback
+    ((4, 24, 256), "rows", None),
+    ((4, 2, 16, 384), "rows", None),
+    ((4, 8, 1280), "rows", None),          # wide like the head: 2 col blocks
+    ((4, 40, 256), "rows", 4096),          # 16-row blocks overhang 40 rows
+    ((4, 20, 128), "flat", None),          # A % 8 != 0
+    ((4, 3, 7), "flat", None),
+    ((5, 1000), "flat", None),
+]
+
+
+@pytest.mark.parametrize("weighting", ["uniform", "weighted", "batched"])
+@pytest.mark.parametrize("shape,view,block_m", VIEW_CASES,
+                         ids=["x".join(map(str, c[0])) for c in VIEW_CASES])
+def test_view_matches_oracle(shape, view, block_m, weighting):
+    """Each view of the single-pass kernel matches kernels/ref.py, with
+    uniform, (K,) and (K, 3) weights, contaminated by a 100-shift."""
+    k = shape[0]
+    kx, ka = jax.random.split(jax.random.key(sum(shape)))
+    x = jax.random.normal(kx, shape).at[-1].add(100.0)
+    eng = ops.AggregationEngine(interpret=True, block_m=block_m)
+    with ops.record_workloads() as rec:
+        if weighting == "batched":
+            a = jax.random.uniform(ka, (k, 3), minval=0.0, maxval=1.0)
+            got = eng.aggregate_batched(x, a)
+            want = ref.mm_aggregate_batched_ref(x.reshape(k, -1), a)
+            want = want.reshape((3,) + shape[1:])
+        else:
+            a = None if weighting == "uniform" else \
+                jax.random.uniform(ka, (k,), minval=0.05, maxval=2.0)
+            got = eng.aggregate(x, a)
+            want = ref.mm_aggregate_ref(x, a)
+    assert rec[0]["view"] == view and rec[0]["path"] == "single"
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_qwen3_leaves_take_the_rows_view():
+    """Lowering the engine launches of a qwen3-0.6b gradient (published
+    widths, 2 layers, K=4, untied head) records view == "rows" for every
+    leaf whose trailing matrix (A, B) has B % 128 == 0 and A % 8 == 0,
+    and the flat view for the rest (norm vectors)."""
+    import dataclasses
+    from repro.configs import qwen3_0p6b
+    from repro.models import model as M
+
+    cfg = dataclasses.replace(qwen3_0p6b.MODEL, num_layers=2,
+                              tie_embeddings=False)
+    params = jax.eval_shape(lambda: M.init_model(jax.random.key(0), cfg))
+    eng = ops.AggregationEngine(interpret=True)
+    n_rows = 0
+    for leaf in jax.tree.leaves(params):
+        x = jax.ShapeDtypeStruct((4,) + leaf.shape, jnp.float32)
+        with ops.record_workloads() as rec:
+            jax.jit(eng.aggregate).lower(x)
+        (r,) = rec
+        s = leaf.shape
+        own = len(s) >= 2 and s[-1] % 128 == 0 and s[-2] % 8 == 0
+        assert r["view"] == ("rows" if own else "flat"), (s, r)
+        if own:
+            assert (r["rows"], r["cols"]) == (math.prod(s[:-1]), s[-1])
+            n_rows += 1
+    # embedding, head and the seven projection matrices
+    assert n_rows == 9
+
+
 def test_input_stream_independent_of_n():
     """One-residency contract: at fixed tile sizes, the number of input
     blocks fetched from HBM (and the bytes streamed) is the same for
     every N -- the weight-column axis lives in the kernel body, not the
-    launch grid."""
-    fetches = {
-        n: K.launch_plan(32, 1 << 14, n, block_m=256).input_block_fetches
-        for n in (1, 5, 32)}
-    assert len(set(fetches.values())) == 1, fetches
-    in_bytes = {
-        n: K.launch_plan(32, 1 << 14, n, block_m=256).input_bytes
-        for n in (1, 5, 32)}
-    assert len(set(in_bytes.values())) == 1, in_bytes
+    launch grid -- for the flat view and for a leaf's own matrix."""
+    for shape in ((1 << 14,), (64, 256)):
+        plans = {n: K.launch_plan(32, 1 << 14, n, block_m=4096, shape=shape)
+                 for n in (1, 5, 32)}
+        fetches = {n: p.input_block_fetches for n, p in plans.items()}
+        assert len(set(fetches.values())) == 1, fetches
+        in_bytes = {n: p.input_bytes for n, p in plans.items()}
+        assert len(set(in_bytes.values())) == 1, in_bytes
+        # every coordinate is fetched once per K block
+        p = plans[1]
+        assert p.grid[0] * p.block_rows * p.block_cols == 1 << 14
     # and the batched entry point is still exactly ONE pallas_call
     x = jnp.zeros((8, 256))
     a = jnp.full((8, 4), 0.25)
@@ -244,18 +325,29 @@ def test_block_k_streaming_invariance():
 
 def test_m_padding_is_zero_not_inf():
     """Regression: the M pad used +inf columns, so the in-kernel MAD
-    computed inf - inf = NaN.  The pad must be inert zeros."""
+    computed inf - inf = NaN.  The pad must be inert zeros -- in the
+    single-pass flat view (M padded to whole 128-lane rows) and in the
+    two-pass (K, M) operand."""
     x = jax.random.normal(jax.random.key(3), (5, 130))
     a = jnp.full((5,), 0.2)
     plan = K.launch_plan(5, 130, 1, block_m=512)
-    xp, ap, _ = K._pad_inputs(x, a.reshape(5, 1), plan=plan)
-    assert xp.shape == (6, 512)
-    pad_cols = xp[:, 130:]
+    assert (plan.view, plan.rows, plan.cols) == ("flat", 2, 128)
+    xv = K._view_inputs(x, plan)
+    # the odd K is padded to the (even) K block with zero rows, which
+    # the kernel never reads: it sorts only the K real agent slabs
+    assert xv.shape == (6, 2, 128)
+    pad_cols = xv.reshape(6, 256)[:, 130:]
     assert bool(jnp.isfinite(pad_cols).all()), "M pad must be finite"
     np.testing.assert_allclose(pad_cols, 0.0)
-    # K pad rows stay +inf sentinels (sorted to the end), weight 0
-    assert bool(jnp.isinf(xp[5, :130]).all())
-    np.testing.assert_allclose(ap[5], 0.0)
+    np.testing.assert_array_equal(xv.reshape(6, 256)[:5, :130], x)
+    plan2 = K.launch_plan(5, 130, 1, block_m=512, path="two_pass")
+    xp, ap, _ = K._pad_inputs(x, a.reshape(5, 1), plan=plan2)
+    assert xp.shape == (plan2.k_pad, 512)
+    assert bool(jnp.isfinite(xp[:, 130:]).all()), "M pad must be finite"
+    np.testing.assert_allclose(xp[:, 130:], 0.0)
+    # two-pass K pad rows stay +inf sentinels (sorted to the end), weight 0
+    assert bool(jnp.isinf(xp[5:, :130]).all())
+    np.testing.assert_allclose(ap[5:], 0.0)
 
 
 def test_kernel_clean_under_debug_nans():

@@ -81,7 +81,11 @@ def test_substrate_lm_pallas_finite_with_per_layout_audit():
         assert plan["n_out"] == 1            # Mode A aggregates per leaf
         assert plan["k_pad"] == sp.num_agents
         assert plan["block_m"] >= 128 and plan["grid"][0] >= 1
-        assert plan["m_total"] % plan["block_m"] == 0
+        # the grid's tiles cover the (R, C) view the kernel reads
+        assert plan["m_total"] == plan["rows"] * plan["cols"]
+        assert plan["grid"][0] * plan["block_rows"] * plan["block_cols"] \
+            >= plan["m_total"]
+        assert plan["view"] in ("rows", "flat")
     json_row = res.to_row()
     assert json_row["launch_audit"]["n_layouts"] == audit["n_layouts"]
 

@@ -90,7 +90,7 @@ def test_paired_bitonic_weighted_median_under_ties(pattern):
         planes = jnp.broadcast_to(col[:, None, None], (p, 1, x.shape[1]))
         xv = jnp.where(finite, x, jnp.inf)   # sentinel convention
         xs, (ws,) = K._bitonic_sort_rows(xv, (planes,))
-        got = K._weighted_median_planes(xs, ws)[0]
+        got = K._wquantile_planes(xs, ws, 0.5)[0]
         want = location.weighted_median(
             jnp.where(finite, x, 0.0) if not bool(finite.all()) else x, col)
         if bool(finite.all()):
@@ -128,3 +128,52 @@ def test_compare_exchange_count_is_subquadratic():
         got = _count_compare_passes(p)
         assert got == stages, (p, got)
         assert got < p or p == 2   # strictly fewer passes than odd-even
+
+
+# ---------------------------------------------------------------------------
+# the single-pass kernel's network over a list of agent slabs
+# ---------------------------------------------------------------------------
+
+def _slabs(x):
+    return [x[i] for i in range(x.shape[0])]
+
+
+@pytest.mark.parametrize("pattern", PATTERNS)
+@pytest.mark.parametrize("k", [2, 3, 4, 5, 10, 33])
+def test_slab_network_matches_jnp_sort(pattern, k):
+    """The slab network equals jnp.sort for every agent count, the
+    power-of-two top-up existing only as trace-time sentinels, which
+    end up last."""
+    x = _adversarial(pattern, k, 23).reshape(k, 1, 23)
+    got, _ = K._sort_slabs(_slabs(x))
+    p = K.next_pow2(k)
+    assert len(got) == p and all(g is None for g in got[k:])
+    np.testing.assert_array_equal(np.stack([np.asarray(g) for g in got[:k]]),
+                                  np.asarray(jnp.sort(x, axis=0)),
+                                  err_msg=f"{pattern} k={k}")
+
+
+@pytest.mark.parametrize("k", [3, 4, 16, 33])
+def test_slab_network_carries_follow_stable_argsort_distinct(k):
+    x = jax.random.permutation(
+        jax.random.key(k), jnp.arange(k * 11, dtype=jnp.float32)
+    ).reshape(k, 1, 11)
+    w = jax.random.uniform(jax.random.key(k + 1), (k, 1, 11))
+    xs, (ws,) = K._sort_slabs(_slabs(x), (_slabs(w),))
+    want_x, want_w = ref.paired_sort_ref(x[:, 0], w[:, 0])
+    np.testing.assert_array_equal(np.stack(xs[:k])[:, 0], np.asarray(want_x))
+    np.testing.assert_array_equal(np.stack(ws[:k])[:, 0], np.asarray(want_w))
+
+
+@pytest.mark.parametrize("pattern", ("random", "ties", "constant"))
+@pytest.mark.parametrize("k", [5, 16])
+def test_slab_weighted_median_matches_oracle(pattern, k):
+    x = _adversarial(pattern, k, 19)
+    col = jax.random.uniform(jax.random.key(k), (k,), minval=0.05, maxval=1.0)
+    col = col / jnp.sum(col)
+    planes = [jnp.full((19,), c) for c in col]
+    xs, (ws,) = K._sort_slabs(_slabs(x), (planes,))
+    got = K._weighted_median_slabs(xs, ws)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(location.weighted_median(x, col)),
+                               atol=1e-6, err_msg=pattern)

@@ -54,10 +54,12 @@ def one_chip(topo):
 def _compile(sharding, k, m, n=None, *, dtype=jnp.float32, weighted=False,
              block_m=None, block_k=None):
     """Compile one engine launch for the described chip; returns the
-    compiled program and the workload the engine resolved."""
+    compiled program and the workload the engine resolved.  ``m`` is
+    the per-agent coordinate count or shape."""
     eng = ops.AggregationEngine(interpret=False, block_m=block_m,
                                 block_k=block_k)
-    x = jax.ShapeDtypeStruct((k, m), dtype, sharding=sharding)
+    shape = (m,) if isinstance(m, int) else tuple(m)
+    x = jax.ShapeDtypeStruct((k,) + shape, dtype, sharding=sharding)
     if n is not None:
         a = jax.ShapeDtypeStruct((k, n), jnp.float32, sharding=sharding)
         fn, args = eng.aggregate_batched, (x, a)
@@ -92,6 +94,28 @@ def _compile(sharding, k, m, n=None, *, dtype=jnp.float32, weighted=False,
 def test_single_pass_compiles_for_v5e(one_chip, k, m, dtype, weighted):
     _, rec = _compile(one_chip, k, m, dtype=dtype, weighted=weighted)
     assert rec["path"] == "single"
+
+
+@pytest.mark.parametrize("k,shape", [
+    (4, (152064, 1024)),      # the train step's embedding, its own matrix
+    (4, (61440, 1024)),       # w_down of 20 stacked layers
+])
+def test_rows_view_compiles_for_v5e_without_copies(one_chip, k, shape):
+    """The qwen3 train step's leaf geometries read as their own (rows,
+    lanes) matrix: the compiled program is the kernel alone, with no
+    layout copy before or after it."""
+    compiled, rec = _compile(one_chip, k, shape)
+    assert (rec["path"], rec["view"]) == ("single", "rows")
+    text = compiled.as_text()
+    assert not re.search(r"= \S+ (copy|copy-start|fusion)\(", text), text
+
+
+def test_fedbuff_k10_weighted_compiles_for_v5e(one_chip):
+    """K=10 weighted at ResNet-18's 11,689,512 coordinates (the FedBuff
+    buffer): the agent axis leads the kernel's blocks, so K no longer
+    has to be a multiple of the 8-row sublane tile."""
+    _, rec = _compile(one_chip, 10, 11_689_512, weighted=True)
+    assert (rec["path"], rec["view"]) == ("single", "flat")
 
 
 @pytest.mark.parametrize("k,m,n", [
